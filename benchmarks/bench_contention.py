@@ -28,8 +28,8 @@ Four measurements per job count |J| (16 / 64 / 256 by default):
      ``params={"placement": "columnar"}`` (the whole sweep x bisect forest
      advanced as one [branches, S] array program: vectorised argmin picks,
      Eq. (16) pool checks and batched refined-rho re-checks, jit-fused
-     per step under x64 -- the bench enables ``jax_enable_x64`` so the
-     "auto" backend resolves to "jit") vs ``"scalar"`` (the per-branch
+     per step -- the "auto" backend resolves to "jit" on CPU) vs
+     ``"scalar"`` (the per-branch
      ``try_place`` walk -- the oracle, and the faster CPU path at every
      measured size).  The final (theta, kappa, placements) are asserted
      identical -- CI's bench smoke fails on divergence.  Each row
@@ -431,11 +431,6 @@ def main() -> None:
                     help="add the |J|=100000 schedule+simulate point "
                          "(minutes; excluded from --quick)")
     args = ap.parse_args()
-    # The jit-fused columnar backend is gated on float64 (the
-    # bit-identity precondition); enable it up front so "auto"
-    # resolves to "jit" and the placement rows measure the fast path.
-    import jax
-    jax.config.update("jax_enable_x64", True)
 
     sizes = [16, 64] if args.quick else [16, 64, 256]
     report = {"bench": "contention-engine",
@@ -468,8 +463,8 @@ def main() -> None:
               f"  x{row['end_to_end_speedup']:.2f}"
               f"  identical={row['speculative_identical_to_sequential']}")
     # Jitted-columnar-vs-scalar identity is part of the --quick CI
-    # smoke too (hard assert inside bench_placement; x64 is on, so
-    # "auto" resolves to the jit backend).
+    # smoke too (hard assert inside bench_placement; "auto" resolves to
+    # the jit backend on CPU).
     for n in (sizes if args.quick else [256, 1024, 4096, 16384]):
         row = bench_placement(n)
         report["placement"].append(row)

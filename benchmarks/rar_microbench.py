@@ -1,56 +1,55 @@
 """RAR vs fused all-reduce micro-benchmark (§3 / §Perf ablation).
 
-Runs in a subprocess with 8 forced host devices so the parent process
-keeps its single-device view.  Reports wall time per gradient exchange and
-the HLO collective schedule of each variant (2(w-1) collective-permutes vs
-one fused all-reduce) — the structural comparison that carries to TPU."""
+Runs in this process on every device present (a 1-D ``("data",)`` mesh),
+so on a four-chip host it is a four-chip ring.  Reports wall time per
+gradient exchange and the HLO collective schedule of each variant
+(2(w-1) collective-permutes vs one fused all-reduce), each row tagged with
+the platform, device kind and device count it ran on.
+
+    PYTHONPATH=src python benchmarks/rar_microbench.py
+"""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
-_CODE = """
-import time, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro.dist.rar import ring_all_reduce
-
-mesh = jax.make_mesh((8,), ("data",))
-x = jnp.ones((8, 1 << 20), jnp.float32)          # 4 MiB per shard
-
-def bench(fn, tag):
-    jitted = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
-                                   out_specs=P("data")))
-    compiled = jitted.lower(x).compile()
-    txt = compiled.as_text()
-    permutes = txt.count("collective-permute(")
-    allreduces = txt.count("all-reduce(")
-    jitted(x).block_until_ready()
-    t0 = time.time()
-    for _ in range(20):
-        out = jitted(x)
-    out.block_until_ready()
-    us = (time.time() - t0) / 20 * 1e6
-    print(f"{tag},{us:.1f},permutes={permutes};allreduces={allreduces}")
-
-bench(lambda x: ring_all_reduce(x, "data"), "rar_ring_2w-1_steps")
-bench(lambda x: jax.lax.psum(x, "data"), "xla_fused_allreduce")
-"""
+import time
 
 
-def run(verbose: bool = True) -> list[str]:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = "src"
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
-                         capture_output=True, text=True, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    lines = [l for l in out.stdout.splitlines() if "," in l]
+def run(verbose: bool = True, shard_elems: int = 1 << 20) -> list[str]:
+    """``name,us_per_call,derived`` rows for the ring and the fused psum."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist.rar import ring_all_reduce
+    from repro.launch.mesh import make_mesh
+
+    devices = jax.devices()
+    w = len(devices)
+    mesh = make_mesh((w,), ("data",))
+    x = jax.device_put(jnp.ones((w, shard_elems), jnp.float32),
+                       NamedSharding(mesh, P("data")))
+    where = f"{devices[0].platform}:{devices[0].device_kind}x{w}"
+    lines = []
+    for tag, fn in (("rar_ring_2w-1_steps",
+                     lambda v: ring_all_reduce(v, "data")),
+                    ("xla_fused_allreduce",
+                     lambda v: jax.lax.psum(v, "data"))):
+        jitted = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                                       out_specs=P("data")))
+        txt = jitted.lower(x).compile().as_text()
+        permutes = txt.count("collective-permute(")
+        allreduces = txt.count("all-reduce(")
+        jitted(x).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = jitted(x)
+        out.block_until_ready()
+        us = (time.perf_counter() - t0) / 20 * 1e6
+        lines.append(f"{tag},{us:.1f},permutes={permutes};"
+                     f"allreduces={allreduces};devices={where}")
     if verbose:
-        for l in lines:
-            print("  " + l)
+        for line in lines:
+            print("  " + line)
     return lines
 
 

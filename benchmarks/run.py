@@ -67,10 +67,7 @@ def _fig7() -> list[str]:
 
 def _rar() -> list[str]:
     from benchmarks import rar_microbench
-    try:
-        return [f"rar_{l}" for l in rar_microbench.run(verbose=False)]
-    except Exception as e:                                  # noqa: BLE001
-        return [f"rar_microbench,0,SKIPPED({type(e).__name__})"]
+    return [f"rar_{l}" for l in rar_microbench.run(verbose=False)]
 
 
 def _ablations() -> list[str]:

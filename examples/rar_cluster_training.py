@@ -25,13 +25,9 @@ from jax.sharding import Mesh
 
 from repro.core import Cluster, Job, ScheduleRequest, get_policy, simulate
 
-try:
-    from repro.dist.steps import make_rar_train_step
-except ImportError:
-    raise SystemExit("rar_cluster_training needs the repro.dist training "
-                     "substrate (see docs/ARCHITECTURE.md §repro.dist)")
 from repro.configs import get_config
 from repro.data import DataConfig, make_batch
+from repro.dist.steps import make_rar_train_step
 from repro.models import build_model
 from repro.models.config import InputShape
 from repro.optim import adamw

@@ -9,8 +9,5 @@ Subpackages:
 * ``repro.kernels`` -- Pallas TPU kernels (interpret mode on CPU)
 * ``repro.launch``  -- dry-run / train / serve / scheduler-launch drivers
 
-Importing ``repro`` (or any submodule) applies the jax forward-compat
-shims in :mod:`repro._compat` so the whole tree is written once against
-the modern ``jax.shard_map`` / ``jax.set_mesh`` surface.
+The JAX parts target the pinned ``jax==0.9.0`` (see ``pyproject.toml``).
 """
-from repro import _compat as _compat  # noqa: F401  (applies jax shims)

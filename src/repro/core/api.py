@@ -333,18 +333,17 @@ def resolve_placement(params: dict, n_jobs: int | None = None) -> str:
 def resolve_columnar_backend(params: dict) -> str:
     """The request's ``columnar_backend`` param, resolved (default "auto").
 
-    "auto" picks the fused "jit" programs when jax runs in float64
-    (``jax_enable_x64``, the bit-identity precondition) and falls back to
-    "numpy" otherwise; "jit"/"kernel"/"numpy" force a backend ("kernel"
-    routes the same row math through the Pallas kernels of
-    :mod:`repro.kernels.placement`, interpret mode on CPU).  All backends
-    are bit-identical under x64 (pinned by
+    "auto" resolves from the platform: the compiled Pallas "kernel"
+    programs of :mod:`repro.kernels.placement` on TPU, the fused XLA
+    "jit" programs elsewhere (on CPU short batches still take NumPy,
+    below ``DISPATCH_MIN_ROWS``); "jit"/"kernel"/"numpy" force a backend.
+    All backends make the same decisions (pinned by
     ``tests/test_columnar_equivalence.py``).
     """
     backend = params.get("columnar_backend", "auto")
     if backend == "auto":
         import jax
-        return "jit" if jax.config.jax_enable_x64 else "numpy"
+        return "kernel" if jax.default_backend() == "tpu" else "jit"
     from repro.core.columnar import COLUMNAR_BACKENDS
     if backend not in COLUMNAR_BACKENDS:
         raise ValueError(

@@ -25,14 +25,15 @@ with a columnar layout:
     one :func:`~repro.core.contention.scalar_tau_many` /
     :func:`~repro.core.contention.evaluate_stack` pass, and the Eq. (16)
     re-check splits each theta run with a single vectorised comparison;
-  * with ``backend="jit"`` (the default fast path under x64) the pool
-    split, the per-server reductions, both pickers' full GPU orderings and
+  * with ``backend="jit"`` the pool split, the per-server reductions and
     the Eq. (6)-(8) probe scoring each run as ONE fused ``jax.jit``
-    program from :mod:`repro.kernels.placement` -- padded to power-of-two
-    row buckets so nothing retraces across jobs; ``backend="kernel"``
-    routes the same row math through the Pallas kernels (grid step = one
-    branch row, interpret mode on CPU); ``backend="numpy"`` keeps the
-    eager NumPy ops.  All three are bit-identical under x64;
+    program from :mod:`repro.kernels.placement` (int32/float32, with the
+    rows whose decisions sit within the f32 error bound re-checked in
+    float64 on the host) -- padded to power-of-two row buckets so nothing
+    retraces across jobs; ``backend="kernel"`` routes the same row math
+    through the Pallas kernels (interpret mode on CPU, Mosaic on TPU);
+    ``backend="numpy"`` keeps the eager NumPy ops.  All three make the
+    same decisions;
   * branches whose decisions coincide are **re-merged**: a committed step
     is a pure function of (parent row, chosen GPU set), so children are
     deduplicated by the ``(parent row, gpus)`` key -- exactly the state
@@ -139,9 +140,9 @@ class ColumnarPlacement:
     :func:`~repro.core.contention.evaluate_stack` pass over the branch
     stack, ``"reference"`` the per-candidate ``evaluate`` loop.
     ``backend`` selects where the step's array math runs: ``"numpy"``
-    (eager), ``"jit"`` (fused :mod:`repro.kernels.placement` programs;
-    needs ``jax_enable_x64``) or ``"kernel"`` (same programs with the
-    Pallas row kernels; interpret mode on CPU) -- all bit-identical.
+    (eager), ``"jit"`` (fused :mod:`repro.kernels.placement` programs)
+    or ``"kernel"`` (same programs with the Pallas row kernels; interpret
+    mode on CPU) -- all with the same decisions.
     """
 
     #: try_place's escalation-ladder depth (same constant, same semantics).
@@ -159,7 +160,6 @@ class ColumnarPlacement:
         self._kern = None
         if backend != "numpy":
             from repro.kernels import placement as _kern
-            _kern.require_x64()
             self._kern = _kern
         self.u = float(u)
         self.jobs = jobs
@@ -366,8 +366,8 @@ class ColumnarPlacement:
                     rhos.append(r)
             elif self._kern is not None:
                 # One fused Eq. (6)-(8) program over the candidate batch
-                # (bit-identical to the scalar_tau_many expressions).
-                _, rhos = self._kern.score_probes(
+                # (the same slots as the scalar_tau_many expressions).
+                rhos = self._kern.score_probes(
                     cl, job, ys_mat, ps.astype(np.float64),
                     use_kernel=self.backend == "kernel")
             else:
@@ -515,12 +515,13 @@ class ColumnarPlacement:
                 pid_w = np.fromiter((w.pid for w in work), np.int64, nw)
             ord_w = ok_w = None
             # The fused program pays one device dispatch + host rankings
-            # for the whole batch; below DISPATCH_MIN_ROWS that fixed cost
-            # exceeds the stats it replaces, so short batches take the
-            # numpy pickers verbatim (the jit backend is then exactly the
-            # numpy backend until batches grow tall enough to win).
+            # for the whole batch; on CPU, below DISPATCH_MIN_ROWS that
+            # fixed cost exceeds the stats it replaces, so short batches
+            # take the numpy pickers verbatim.
             fused_now = fused and (self.backend == "kernel"
-                                   or nw >= self._kern.DISPATCH_MIN_ROWS)
+                                   or nw >= self._kern.min_dispatch_rows())
+            if fused and not fused_now:
+                self._kern.DISPATCH_COUNTS["host"] += 1
             U_w = self.U[rows_w]
             if fused_now:
                 # One fused program: pools at both extremes, per-server

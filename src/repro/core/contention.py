@@ -104,10 +104,10 @@ def resolve_engine(name: str | None) -> str:
     return name
 
 
-# Backend for stack_model's inner tau reduction: "numpy" (default) or
-# "kernel" (the jitted Pallas kernel in repro.kernels.tau; interpret mode
-# on CPU, compiled Mosaic on TPU).  On CPU the kernel exists for numerics
-# parity and TPU forward-compat, not speed -- hence the opt-in.
+# Backend for stack_model's inner Eq. (6) reduction: "numpy" (default) or
+# "kernel" (the int32 Pallas kernel in repro.kernels.tau; interpret mode
+# on CPU, compiled Mosaic on TPU).  On CPU the interpreter is an emulator,
+# not a fast path -- hence the opt-in.
 TAU_BACKENDS = ("numpy", "kernel")
 TAU_BACKEND = "numpy"
 
@@ -264,10 +264,11 @@ def stack_model(cluster: Cluster, G: np.ndarray, share: np.ndarray,
     straddles nothing, so every other row's contention is exactly as if
     the row were absent.
 
-    When the Pallas tau kernel is enabled (see :func:`tau_backend`), the
-    inner straddle/per-server/max reduction and the Eq. (8) combination
-    run inside one jitted kernel instead of this NumPy pipeline; the
-    candidate axis is the kernel's grid dimension for both term shapes.
+    When the Pallas kernel is enabled (see :func:`tau_backend`), the
+    O(C J S) straddle/per-server/max reduction runs inside it in int32
+    (the candidate axis is its grid dimension) and the Eq. (7)-(8) float
+    terms below stay in float64 here -- the model is the NumPy
+    pipeline's to the bit.
     """
     Y = Y_stack
     if active is not None:
@@ -276,15 +277,14 @@ def stack_model(cluster: Cluster, G: np.ndarray, share: np.ndarray,
     share2 = np.broadcast_to(np.asarray(share), Y.shape[:2])
     compute2 = np.broadcast_to(np.asarray(compute), Y.shape[:2])
     if TAU_BACKEND != "numpy":
-        from repro.kernels.tau import tau_stack
-        p, n_srv_i, tau = tau_stack(cluster, G, share, compute, Y)
+        from repro.kernels.tau import stack_counts
+        p, n_srv_i = stack_counts(G, Y)
     else:
         straddle = (Y > 0) & (Y < G2[:, :, None])      # [C, J, S]
         per_server = straddle.sum(axis=1)              # [C, S]
         p = np.where(straddle, per_server[:, None, :], 0).max(axis=2)
         p = p.astype(np.int64)
         n_srv_i = (Y > 0).sum(axis=2)
-        tau = None                       # derived from the terms below
     k = np.maximum(cluster.xi1 * p, 1.0)
     f = degradation(cluster.alpha, k)
     if cluster.is_heterogeneous:
@@ -298,8 +298,7 @@ def stack_model(cluster: Cluster, G: np.ndarray, share: np.ndarray,
     exchange = 2.0 * share2 / bandwidth
     reduce_t = share2 / speed
     compute_b = compute2
-    if tau is None:
-        tau = exchange + reduce_t + gamma + compute_b
+    tau = exchange + reduce_t + gamma + compute_b
     phi = np.floor(1.0 / tau).astype(np.int64)
     return IterModel(p=p, k=k, bandwidth=bandwidth, gamma=gamma,
                      exchange=exchange, reduce=reduce_t, compute=compute_b,
@@ -796,9 +795,9 @@ def scalar_tau_many(cluster: Cluster, job: Job, p: np.ndarray,
 
     The fused columnar score step (``score_probes`` in
     :mod:`repro.kernels.placement`) re-derives exactly this expression
-    chain on device for tall probe batches -- any change to the
-    operation order here must land there too, or the x64 bit-identity
-    contract pinned by ``tests/test_columnar_equivalence.py`` breaks."""
+    chain in float32 for tall probe batches and re-checks here whatever
+    lies within its error bound -- a change to this expression must land
+    there too (pinned by ``tests/test_columnar_equivalence.py``)."""
     p = np.asarray(p, dtype=np.float64)
     n_srv = np.asarray(n_srv)
     w = float(job.num_gpus)

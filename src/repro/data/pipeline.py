@@ -9,6 +9,7 @@ modality stubs (patches/frames) are unit Gaussians.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Iterator
 
 import numpy as np
@@ -31,7 +32,9 @@ def make_batch(cfg: ModelConfig, shape: InputShape, step: int,
                data_cfg: DataConfig = DataConfig(),
                batch_override: int | None = None) -> dict:
     """Deterministic global batch for (arch, shape, step)."""
-    rng = np.random.default_rng((data_cfg.seed, step, hash(cfg.name) & 0xFFFF))
+    # crc32, not hash(): str hashes are salted per process.
+    rng = np.random.default_rng((data_cfg.seed, step,
+                                 zlib.crc32(cfg.name.encode()) & 0xFFFF))
     B = batch_override or shape.global_batch
     S = shape.seq_len
     if cfg.family == "vlm":

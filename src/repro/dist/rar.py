@@ -27,11 +27,7 @@ import jax.numpy as jnp
 
 def axis_size(axis_name: str) -> int:
     """Static size ``w`` of the mapped ring axis (shard_map body scope)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    import jax.core as jcore  # pragma: no cover - pre-shim fallback
-
-    return int(jcore.axis_frame(axis_name))
+    return int(jax.lax.axis_size(axis_name))
 
 
 def exchange_bytes_per_worker(d: float, w: int) -> float:
@@ -47,14 +43,15 @@ def exchange_bytes_per_worker(d: float, w: int) -> float:
     return 2.0 * d * (w - 1) / w
 
 
-def _ring_chunks(x: jax.Array, w: int) -> jax.Array:
-    """Flatten ``x`` and split into ``w`` equal chunks, zero-padding the
-    tail when ``x.size`` is not a multiple of ``w``.  Returns ``[w, m]``."""
+def _ring_chunks(x: jax.Array, w: int) -> tuple[jax.Array, int]:
+    """Flatten ``x`` and zero-pad it to ``w`` equal chunks of ``m``
+    elements.  Returns the flat ``[w * m]`` vector and ``m``; chunk ``c``
+    is ``flat[c * m : (c + 1) * m]``."""
     flat = x.reshape(-1)
     m = -(-flat.size // w)
     if m * w != flat.size:
         flat = jnp.pad(flat, (0, m * w - flat.size))
-    return flat.reshape(w, m)
+    return flat, m
 
 
 def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
@@ -65,17 +62,20 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     ``ceil(x.size / w)`` elements.
     """
     w = axis_size(axis_name)
-    chunks = _ring_chunks(x, w)
+    flat, m = _ring_chunks(x, w)
     if w == 1:
-        return chunks[0]
+        return flat
     i = jax.lax.axis_index(axis_name)
     # send "left" (j -> j-1): the partial for chunk c starts at worker c-1
     # and accumulates one local contribution per hop until worker c owns it.
     left = [(j, (j - 1) % w) for j in range(w)]
 
     def local_chunk(c):
-        """This worker's contribution for (traced) chunk index ``c``."""
-        return jnp.take(chunks, c % w, axis=0)
+        """This worker's contribution for (traced) chunk index ``c``: a
+        1-D dynamic slice.  (Slicing row ``c`` of a ``[w, m]`` array
+        instead took the TPU compiler minutes at lane-aligned ``m`` of a
+        billion-parameter gradient.)"""
+        return jax.lax.dynamic_slice_in_dim(flat, (c % w) * m, m)
 
     partial = local_chunk(i + 1)
     for t in range(w - 1):
@@ -96,12 +96,20 @@ def ring_all_gather(chunk: jax.Array, axis_name: str) -> jax.Array:
         return chunk
     i = jax.lax.axis_index(axis_name)
     left = [(j, (j - 1) % w) for j in range(w)]
-    out = jnp.zeros((w,) + chunk.shape, chunk.dtype)
-    out = out.at[i % w].set(chunk)
+    m = chunk.size
+
+    def put(out, part, c):
+        """Write ``part`` as chunk ``c`` of the flat result (1-D, as in
+        ``ring_reduce_scatter``: row writes into ``[w, m]`` made the TPU
+        compiler take over ten minutes on a full-width training step)."""
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, part.reshape(-1), (c % w) * m, axis=0)
+
+    out = put(jnp.zeros((w * m,), chunk.dtype), chunk, i)
     buf = chunk
     for t in range(w - 1):
         buf = jax.lax.ppermute(buf, axis_name, left)
-        out = out.at[(i + t + 1) % w].set(buf)
+        out = put(out, buf, i + t + 1)
     return out.reshape((w * chunk.shape[0],) + chunk.shape[1:])
 
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 def _mlstm_kernel(q_ref, k_ref, v_ref, f_ref, fk_ref, i_ref, o_ref,

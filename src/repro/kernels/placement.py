@@ -1,109 +1,133 @@
-"""Jitted pick/check/score programs for the columnar placement engine.
+"""Device programs for the columnar placement engine: pick statistics and
+probe scoring, in int32/float32 with exact host re-checks.
 
 The columnar engine (:class:`repro.core.columnar.ColumnarPlacement`)
 advances every (theta, kappa) branch of the SJF-BCO forest by one job per
-step.  Its per-step array program -- the Eq. (16) feasibility pools
+step.  Its per-step array work -- the Eq. (16) feasibility pools
 (``U + rho/u <= theta + 1e-9``), the per-server busy/feasible-count
 reductions behind the FA-FFP/LBSGF picks, and the Eq. (6)-(8) tau/rho
 scoring of the probed candidates -- is a pile of small dense ops over
-``[rows, N]`` operands, which on the NumPy path pays one dispatch per op.
-This module fuses each half into ONE ``jax.jit`` program:
+``[rows, N]`` operands.  This module fuses each half into ONE program:
 
   * :func:`pick_orders` -- pool threshold counts at each work item's
-    extreme thetas, GPU-id-order per-server busy sums, feasible-slot
-    counts and the FA-FFP best-server selection, in one fused program over
-    a ``[rows, N]`` block padded to a power-of-two row bucket; the stable
-    pick *rankings* then run host-side with NumPy sorts over those
-    bitwise-equal keys (XLA's CPU stable sort is ~10-20x slower than
-    NumPy's on these small rows, so sorting in-program would erase the
-    fusion win);
+    extreme thetas, per-server busy sums (one 0/1 membership matmul),
+    feasible-slot counts and the FA-FFP best server; the stable pick
+    *rankings* then run host-side with NumPy sorts;
   * :func:`score_probes` -- Eq. (8) tau and the rho-hat slot count for a
-    padded batch of probed candidates, reusing
-    :func:`repro.kernels.tau`'s hetero-aware term layout (per-server
-    speed floors, shared/isolated uplinks with +inf where absent).
+    padded batch of probed candidates, with the heterogeneous worst-member
+    device terms (per-server speed floors, shared/isolated uplinks with
+    +inf where absent).
 
-Row shapes are padded to power-of-two buckets so the programs retrace only
-per (bucket, cluster) -- never per job (pinned by the compile-count guard
-in ``tests/test_columnar_equivalence.py``).  With ``use_kernel=True`` the
-same row math runs inside Pallas kernels (grid step = one branch row, the
-whole row reduction in VMEM; interpret mode on CPU, real lowering on TPU)
--- the kernels share the jnp expressions with the fast path, so all three
-backends (numpy / jit / kernel) are bit-identical under ``jax_enable_x64``:
-the per-server sums replay ``np.bincount``'s GPU-id addition order as a
-statically unrolled in-order block reduction, and every sort is a stable
-sort over bitwise-equal keys.  Without x64 jax computes in float32 and the
-fused path is rejected (:func:`require_x64`) rather than silently diverging
-from the scalar oracle.
+Each program has a plain ``jax.jit`` form and a Pallas form
+(``use_kernel=True``: one grid step per :data:`ROW_BLOCK` rows, the row
+reductions in VMEM; interpret mode on CPU, Mosaic on TPU).  Rows are
+padded to power-of-two buckets, so the programs retrace only per (bucket,
+cluster), never per job.
+
+**Precision contract: the same decisions as the float64 host oracle.**
+The programs compute in int32/float32 (Mosaic has no 64-bit types, and
+XLA's emulated f64 on TPU is not IEEE binary64).  Integer reductions --
+pool counts, per-server feasible counts, the FA-FFP fit test -- are exact
+given exact compares.  Every float that decides a placement is screened:
+the program also returns, per row, whether any such float lies within its
+f32 error bound of its threshold or its tie, and those rows are recomputed
+in float64 on the host by the NumPy oracle expressions.  The bounds:
+
+  * Eq. (16) pools: ``|V - T| <= POOL_REL * (|V| + |T|)``.  V = U + rho/u
+    and T = theta + 1e-9 reach f32 through <= 3 roundings of non-negative
+    terms (< 3 * 2^-24 relative); POOL_REL = 2^-20 leaves a 5x margin.
+  * FA-FFP ``-load`` tie-break and the LBSGF ``load/cap`` ranking: a
+    server's busy sum adds at most ``maxcap`` f32-rounded clocks (relative
+    error < (maxcap + 2) * 2^-24 for non-negative terms, at
+    ``Precision.HIGHEST``, where the TPU's matmul keeps f32 accuracy); the
+    screen uses ``load_rel = (maxcap + 8) * 2^-20`` (16x) per operand.  Two
+    loads that are both exactly zero are an exact tie: every nonzero clock
+    is a sum of rho/u >= 1/u, far above f32's smallest normal, so f32 zero
+    <=> float64 zero.
+  * Eq. (8) ``phi = floor(1/tau)``: tau is a sum of four non-negative terms
+    built from <= 9 rounded inputs and divisions (< 20 * 2^-24 relative
+    through the reciprocal); SCORE_REL = 2^-17 (>6x) around each integer.
+    ``ceil(iters / phi)`` is then made exact in int32 arithmetic.
+
+Rows that pass every screen return the device's integers (and its f32
+``load/cap`` keys, whose order then equals the float64 order); the rest
+return the host recompute.  ``DISPATCH_COUNTS`` records both.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+__all__ = ["pick_orders", "score_probes", "compile_counts",
+           "DISPATCH_COUNTS", "min_dispatch_rows"]
 
-__all__ = ["pick_orders", "score_probes", "require_x64", "compile_counts"]
+#: Rows per kernel grid step (one TPU sublane tile of 32-bit values);
+#: also the smallest padded row bucket.
+ROW_BLOCK = 8
 
-#: Smallest padded row bucket (power of two).
-MIN_BUCKET = 4
-
-#: Below this many rows the stats run in NumPy instead of the device
-#: program: one CPU dispatch+fetch round-trip (~300us measured on this
-#: host) costs more than the reductions it replaces.  Calibrated on the
-#: 32-server Philly cluster at |J| = 8192 (thresh 32 -> 37.6s, thresh
-#: 64 -> 32.9s vs 32.8s pure NumPy; the work-group histogram tops out
-#: near 48 rows there, so 64 means "dispatch only on genuinely tall
-#: batches").  ``use_kernel=True`` always dispatches (the Pallas path
-#: is about lowering, not CPU speed).
+#: CPU only: below this many rows the stats run in NumPy instead of the
+#: device program -- one CPU dispatch+fetch round-trip (~300us on the
+#: host it was calibrated on) costs more than the reductions it replaces
+#: (32-server Philly cluster at |J| = 8192: thresh 32 -> 37.6s, 64 ->
+#: 32.9s vs 32.8s pure NumPy).  On an accelerator every batch dispatches;
+#: ``use_kernel=True`` always dispatches.
 DISPATCH_MIN_ROWS = 64
 
+#: Screening bounds (see the module docstring).
+POOL_REL = 2.0 ** -20
+SCORE_REL = 2.0 ** -17
+_PHI_MAX = 2.0 ** 23            # beyond this floor(1/tau) is not f32-exact
 
-def require_x64() -> None:
-    """Reject the fused path when jax would compute in float32.
+#: Calls that ran the device program / took the host NumPy path below the
+#: CPU gate, rows dispatched, and rows re-checked in float64 on the host.
+DISPATCH_COUNTS = {"device": 0, "host": 0, "rows": 0, "rechecked": 0}
 
-    The columnar engine's bit-identity contract against the scalar oracle
-    only holds in float64; callers resolve ``columnar_backend="auto"`` to
-    "numpy" in that case, so reaching this error means "jit"/"kernel" was
-    forced explicitly.
-    """
-    if not jax.config.jax_enable_x64:
-        raise RuntimeError(
-            "columnar_backend='jit'/'kernel' needs jax_enable_x64 for "
-            "bit-identity with the scalar oracle; enable x64 "
-            '(jax.config.update("jax_enable_x64", True)) or use '
-            "columnar_backend='numpy'")
+
+def min_dispatch_rows() -> int:
+    """Smallest batch that runs on the device (the CPU-only gate)."""
+    return DISPATCH_MIN_ROWS if jax.default_backend() == "cpu" else 1
+
+
+def _interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode: explicit, else on CPU backends only."""
+    return jax.default_backend() == "cpu" if interpret is None else interpret
 
 
 def _bucket(n: int) -> int:
-    """Power-of-two padding bucket for ``n`` rows (>= MIN_BUCKET)."""
-    return max(MIN_BUCKET, 1 << (max(1, n) - 1).bit_length())
+    """Power-of-two padding bucket for ``n`` rows (>= ROW_BLOCK)."""
+    return max(ROW_BLOCK, 1 << (max(1, n) - 1).bit_length())
+
+
+def _pad_rows(a: np.ndarray, R: int, fill=0) -> np.ndarray:
+    """``a`` with its leading axis padded to ``R`` rows of ``fill``."""
+    if a.shape[0] == R:
+        return a
+    pad = np.full((R - a.shape[0],) + a.shape[1:], fill, dtype=a.dtype)
+    return np.concatenate([a, pad])
 
 
 @functools.lru_cache(maxsize=64)
 def _cluster_consts(cluster) -> dict:
-    """Per-cluster constant arrays for the fused programs (cached; the
-    Cluster dataclass is frozen/hashable).  ``block_idx``/``block_valid``
-    drive the GPU-id-order per-server block sums: server ``s`` owns the
-    contiguous GPU range ``[offset_s, offset_s + cap_s)``, padded to the
-    cluster's max capacity with clipped (masked-out) indices."""
+    """Per-cluster constant operands (cached; the Cluster dataclass is
+    frozen/hashable).  ``member`` [N, S] is the 0/1 GPU-to-server
+    membership the per-server sums multiply by."""
     caps = cluster.capacities_array
-    offsets = np.concatenate([[0], np.cumsum(caps)[:-1]])
-    maxcap = int(caps.max())
-    block_idx = np.minimum(offsets[:, None] + np.arange(maxcap)[None, :],
-                           cluster.num_gpus - 1)
-    block_valid = np.arange(maxcap)[None, :] < caps[:, None]
+    S = cluster.num_servers
+    member = (cluster.gpu_server[:, None] == np.arange(S)[None, :])
+    f32 = np.float32
     return {
-        # Device-committed constants (passed into the jit programs; the
-        # pjit fast path sees committed arrays and skips the transfer).
-        "block_idx": jnp.asarray(block_idx),
-        "block_valid": jnp.asarray(block_valid),
-        "speed_floor": jnp.asarray(cluster.server_speed_floor),
-        "uplink_shared": jnp.asarray(cluster.uplink_shared_or_inf),
-        "uplink_isolated": jnp.asarray(cluster.uplink_isolated_or_inf),
+        "member": jnp.asarray(member.astype(f32)),
+        "caps": jnp.asarray(caps.astype(f32)[None, :]),
+        "load_rel": float((int(caps.max()) + 8) * 2.0 ** -20),
+        "speed_floor": jnp.asarray(cluster.server_speed_floor.astype(f32)[None, :]),
+        "uplink_shared": jnp.asarray(cluster.uplink_shared_or_inf.astype(f32)[None, :]),
+        "uplink_isolated": jnp.asarray(cluster.uplink_isolated_or_inf.astype(f32)[None, :]),
         # Host copies for the NumPy ranking half.
         "np_gpu_server": np.asarray(cluster.gpu_server),
         "np_caps": np.asarray(caps),
@@ -111,117 +135,142 @@ def _cluster_consts(cluster) -> dict:
 
 
 # --------------------------------------------------------------------------
-# Row math (shared verbatim by the jnp fast path and the Pallas kernels)
+# Row math (shared verbatim by the jnp programs and the Pallas kernels)
 # --------------------------------------------------------------------------
 
 
-def _pool_row_math(U, tlo, thi, rho_u, G, block_idx, block_valid):
-    """Per-row pool/threshold/server reductions for a ``[B, N]`` block.
+def _i32(x):
+    return x.astype(jnp.int32)
 
-    Returns ``(V, feas, c_lo, c_hi, load, cnt, best_srv, has_fit)``.  The
-    per-server busy sums replay ``np.bincount(gpu_server, weights=U)``'s
-    sequential GPU-id addition order as a statically unrolled in-order
-    reduction over each server's contiguous block (trailing masked lanes
-    add +0.0, which is the identity for the non-negative clocks), so the
-    FA-FFP/LBSGF sort keys are bitwise equal to the NumPy pickers'."""
+
+def _near(a, b, rel):
+    """``a`` within the relative screening bound of ``b``."""
+    return jnp.abs(a - b) <= rel * (jnp.abs(a) + jnp.abs(b))
+
+
+def _pool_row_math(U, t_lo, t_hi, rho_u, G, member, *, load_rel):
+    """Pools, thresholds and per-server reductions for a ``[R, N]`` block.
+
+    ``t_lo``/``t_hi``/``rho_u``/``G`` are ``[R, 1]`` columns (thresholds
+    already include the +1e-9).  Returns ``[R, 1]`` int32 columns
+    ``(c_lo, c_hi, best_srv, has_fit, pool_unsure, fa_unsure)`` and the
+    ``[R, S]`` float32 per-server busy sums."""
     N = U.shape[-1]
-    V = U + rho_u[:, None]
-    feas = V <= tlo[:, None] + 1e-9                     # Eq. (16) pool
-    c_lo = jnp.sum(feas, axis=-1)
-    c_hi = jnp.sum(V <= thi[:, None] + 1e-9, axis=-1)
-    Ub = U[:, block_idx]                                # [B, S, maxcap]
-    Fb = feas[:, block_idx] & block_valid[None]
-    cnt = jnp.sum(Fb, axis=-1)                          # exact: bool counts
-    load = jnp.zeros(U.shape[:-1] + block_idx.shape[:1], U.dtype)
-    for i in range(block_idx.shape[1]):                 # GPU-id order
-        load = load + jnp.where(block_valid[None, :, i], Ub[:, :, i], 0.0)
+    S = member.shape[-1]
+    V = U + rho_u
+    feas = V <= t_lo                                    # Eq. (16) pool
+    c_lo = jnp.sum(_i32(feas), axis=-1, keepdims=True)
+    c_hi = jnp.sum(_i32(V <= t_hi), axis=-1, keepdims=True)
+    pool_unsure = jnp.max(
+        _i32(_near(V, t_lo, POOL_REL) | _near(V, t_hi, POOL_REL)),
+        axis=-1, keepdims=True)
+    hi = jax.lax.Precision.HIGHEST
+    load = jnp.dot(U, member, precision=hi,
+                   preferred_element_type=jnp.float32)
+    # 0/1 products summed in f32: exact integer counts at any precision.
+    cnt = _i32(jnp.dot(feas.astype(jnp.float32), member, precision=hi,
+                       preferred_element_type=jnp.float32))
     # FA-FFP best server: lexicographic min over (feasible slots left,
-    # -occupancy, server id) as staged masked argmins -- the same total
-    # order as the scalar lexsort, ties resolved by first index.
+    # -occupancy, server id) as staged masked reductions -- ties resolved
+    # by first index, as the host lexsort does.
     fits = cnt >= G
-    has_fit = jnp.any(fits, axis=-1)
+    has_fit = jnp.max(_i32(fits), axis=-1, keepdims=True)
     k_fit = jnp.where(fits, cnt - G, N + 1)
-    k_occ = jnp.where(fits, -load, jnp.inf)
     t1 = k_fit == jnp.min(k_fit, axis=-1, keepdims=True)
-    k2 = jnp.where(t1, k_occ, jnp.inf)
-    t2 = t1 & (k2 == jnp.min(k2, axis=-1, keepdims=True))
-    best_srv = jnp.argmax(t2, axis=-1)
-    return V, feas, c_lo, c_hi, load, cnt, best_srv, has_fit
+    k2 = jnp.where(t1, load, -1.0)
+    lmax = jnp.max(k2, axis=-1, keepdims=True)
+    sid = jax.lax.broadcasted_iota(jnp.int32, load.shape, 1)
+    best = jnp.min(jnp.where(t1 & (k2 == lmax), sid, S), axis=-1,
+                   keepdims=True)
+    # Another tied-fit server within the load bound of the winner.
+    tie = t1 & (lmax - load <= load_rel * (lmax + load))
+    fa_unsure = _i32((jnp.sum(_i32(tie), axis=-1, keepdims=True) > 1)
+                     & (lmax > 0) & (has_fit > 0))
+    return c_lo, c_hi, load, best, has_fit, pool_unsure, fa_unsure
 
 
-def _score_row_math(Y, f, gamma, two_share, share, reduce_const, compute,
-                    iters, speed_floor, uplink_sh, uplink_iso, *, hetero,
-                    b_inter, b_intra):
-    """Eq. (6)-(8) tau + rho-hat slots for a ``[B, S]`` candidate block.
+def _score_row_math(Y, f, gamma, scal, speed_floor, uplink_sh, uplink_iso,
+                    *, hetero, b_inter, b_intra):
+    """Eq. (6)-(8) tau -> rho-hat slots for a ``[R, S]`` candidate block.
 
-    Same expressions in the same order as
-    :func:`repro.core.contention.scalar_tau_many` /
-    :func:`~repro.core.contention.slots_for_many`; the hetero branch reuses
-    :func:`repro.kernels.tau`'s term layout (per-server speed floor and
-    shared/isolated uplinks with +inf where the class is absent).
-
-    The contention terms that multiply into a later addition -- k, the
-    degradation f, gamma = xi2 * n_srv -- arrive precomputed from the host:
-    XLA CPU contracts ``a*b + c`` into an FMA inside a fused loop (one ulp
-    off the separately rounded NumPy result, and ``optimization_barrier``
-    does not stop the LLVM-level contraction), so the program keeps only
-    mins, divides, selects and adds, which have no contractible pairs."""
+    ``f``/``gamma`` are ``[R, 1]`` host-computed contention terms, ``scal``
+    the ``[1, 8]`` job row (two_share, share, reduce_const, compute, iters)
+    and the device terms ``[1, S]``.  Returns int32 ``[R, 1]`` columns
+    ``(rho, unsure)``: rho = ceil(iters / max(1, floor(1/tau))) exactly
+    whenever ``unsure`` is 0."""
+    two_share, share = scal[:, 0:1], scal[:, 1:2]
+    reduce_const, compute, iters = scal[:, 2:3], scal[:, 3:4], scal[:, 4:5]
     pos = Y > 0
-    multi = jnp.sum(pos, axis=-1) > 1
+    multi = jnp.sum(_i32(pos), axis=-1, keepdims=True) > 1
     if hetero:
-        inf = jnp.inf
-        speed = jnp.min(jnp.where(pos, speed_floor, inf), axis=-1)
-        bw_sh = jnp.min(jnp.where(pos, uplink_sh, inf), axis=-1)
-        bw_iso = jnp.min(jnp.where(pos, uplink_iso, inf), axis=-1)
+        inf = jnp.float32(jnp.inf)
+        speed = jnp.min(jnp.where(pos, speed_floor, inf), axis=-1,
+                        keepdims=True)
+        bw_sh = jnp.min(jnp.where(pos, uplink_sh, inf), axis=-1,
+                        keepdims=True)
+        bw_iso = jnp.min(jnp.where(pos, uplink_iso, inf), axis=-1,
+                         keepdims=True)
         bw_multi = jnp.minimum(bw_iso, bw_sh / f)
         reduce_t = share / speed
     else:
         bw_multi = b_inter / f
         reduce_t = reduce_const
     bandwidth = jnp.where(multi, bw_multi, b_intra)
-    exchange = two_share / bandwidth
-    # Eq. (8), same left-to-right addition order as the NumPy engines.
-    tau = exchange + reduce_t + gamma + compute
-    phi = jnp.maximum(1.0, jnp.floor(1.0 / tau))
-    rho = jnp.ceil(iters / phi)
-    return tau, rho
+    tau = two_share / bandwidth + reduce_t + gamma + compute   # Eq. (8)
+    inv = 1.0 / tau
+    k = jnp.floor(inv + 0.5)
+    unsure = ((k >= 1.0) & (jnp.abs(inv - k) <= SCORE_REL * inv)) \
+        | (inv >= _PHI_MAX)
+    phi = _i32(jnp.maximum(1.0, jnp.floor(jnp.minimum(inv, _PHI_MAX))))
+    # ceil(iters / phi), corrected to the exact integer quotient.
+    it = _i32(iters)
+    rho = _i32(jnp.ceil(iters / phi.astype(jnp.float32)))
+    rho = jnp.where(rho * phi < it, rho + 1, rho)
+    rho = jnp.where((rho - 1) * phi >= it, rho - 1, rho)
+    return rho, _i32(unsure)
 
 
 # --------------------------------------------------------------------------
-# Pallas kernel bodies (one grid step per branch row, reductions in VMEM)
+# Pallas kernel bodies (one grid step per ROW_BLOCK rows, VMEM reductions)
 # --------------------------------------------------------------------------
 
 
-def _pool_kernel(U_ref, tlo_ref, thi_ref, rho_ref, g_ref, bidx_ref,
-                 bval_ref, V_ref, feas_ref, clo_ref, chi_ref, load_ref,
-                 cnt_ref, best_ref, fit_ref):
-    """One branch row: Eq. (16) pools + per-server reductions in VMEM."""
-    V, feas, c_lo, c_hi, load, cnt, best, fit = _pool_row_math(
-        U_ref[...], tlo_ref[...][:, 0], thi_ref[...][:, 0],
-        rho_ref[...][:, 0], g_ref[0, 0], bidx_ref[...], bval_ref[...] != 0)
-    V_ref[...] = V
-    feas_ref[...] = feas.astype(feas_ref.dtype)
-    clo_ref[...] = c_lo[:, None].astype(clo_ref.dtype)
-    chi_ref[...] = c_hi[:, None].astype(chi_ref.dtype)
+def _pool_kernel(U_ref, tlo_ref, thi_ref, ru_ref, g_ref, m_ref, clo_ref,
+                 chi_ref, load_ref, best_ref, fit_ref, pu_ref, fu_ref, *,
+                 load_rel):
+    """ROW_BLOCK branch rows: Eq. (16) pools + per-server reductions."""
+    c_lo, c_hi, load, best, fit, pu, fu = _pool_row_math(
+        U_ref[...], tlo_ref[...], thi_ref[...], ru_ref[...], g_ref[...],
+        m_ref[...], load_rel=load_rel)
+    clo_ref[...] = c_lo
+    chi_ref[...] = c_hi
     load_ref[...] = load
-    cnt_ref[...] = cnt.astype(cnt_ref.dtype)
-    best_ref[...] = best[:, None].astype(best_ref.dtype)
-    fit_ref[...] = fit[:, None].astype(fit_ref.dtype)
+    best_ref[...] = best
+    fit_ref[...] = fit
+    pu_ref[...] = pu
+    fu_ref[...] = fu
 
 
 def _score_kernel(Y_ref, f_ref, gamma_ref, scal_ref, spd_ref, sh_ref,
-                  iso_ref, tau_ref, rho_ref, *, hetero, b_inter, b_intra):
-    """One candidate row: Eq. (6)-(8) tau + rho-hat slots in VMEM.
+                  iso_ref, rho_ref, un_ref, *, hetero, b_inter, b_intra):
+    """ROW_BLOCK candidate rows: Eq. (6)-(8) tau -> rho-hat slots."""
+    rho, unsure = _score_row_math(
+        Y_ref[...], f_ref[...], gamma_ref[...], scal_ref[...], spd_ref[...],
+        sh_ref[...], iso_ref[...], hetero=hetero, b_inter=b_inter,
+        b_intra=b_intra)
+    rho_ref[...] = rho
+    un_ref[...] = unsure
 
-    ``scal_ref`` packs the five job scalars (two_share, share,
-    reduce_const, compute, iters) into one grid-invariant row."""
-    tau, rho = _score_row_math(
-        Y_ref[...], f_ref[...][:, 0], gamma_ref[...][:, 0], scal_ref[0, 0],
-        scal_ref[0, 1], scal_ref[0, 2], scal_ref[0, 3], scal_ref[0, 4],
-        spd_ref[0], sh_ref[0], iso_ref[0], hetero=hetero,
-        b_inter=b_inter, b_intra=b_intra)
-    tau_ref[...] = tau[:, None]
-    rho_ref[...] = rho[:, None]
+
+def _row_spec(width):
+    return pl.BlockSpec((ROW_BLOCK, width), lambda b: (b, 0))
+
+
+def _whole_spec(shape):
+    return pl.BlockSpec(shape, lambda b: (0, 0))
+
+
+_PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 # --------------------------------------------------------------------------
@@ -229,114 +278,119 @@ def _score_kernel(Y_ref, f_ref, gamma_ref, scal_ref, spd_ref, sh_ref,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def _pool_stats_jit(U, tlo, thi, rho_u, G, block_idx, block_valid, *,
-                    use_kernel, interpret):
-    """One fused program: pools, thresholds and per-server reductions.
+@functools.partial(jax.jit, static_argnames=("load_rel", "use_kernel",
+                                             "interpret"))
+def _pool_stats_jit(U, t_lo, t_hi, rho_u, G, pid, need, member, caps, *,
+                    load_rel, use_kernel, interpret):
+    """One program: pools, thresholds, per-server reductions, screens.
 
-    Everything *sortless* of the pick pipeline runs here -- the charged
-    clocks, both extreme-theta pool counts, the GPU-id-order busy sums,
-    feasible-slot counts and the FA-FFP best-server argmin.  The stable
-    rankings themselves stay on the host (NumPy's stable sorts beat XLA's
-    CPU variadic sort by an order of magnitude on these small rows, and
-    host sorting over bitwise-equal keys keeps bit-identity trivial).
-    """
+    Returns ``(c_lo, c_hi, key, best_srv, has_fit, unsure)``: ``key`` is
+    the LBSGF ``load/cap`` ranking key, and ``unsure`` flags rows whose
+    decisions the float64 host must recompute (pool thresholds; the
+    FA-FFP tie-break for picker 0, the LBSGF ranking for picker 1)."""
     B, N = U.shape
-    itype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
+    S = member.shape[1]
     if use_kernel:
-        S, maxcap = block_idx.shape
+        col = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         outs = pl.pallas_call(
-            _pool_kernel,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, N), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (0, 0)),
-                pl.BlockSpec((S, maxcap), lambda b: (0, 0)),
-                pl.BlockSpec((S, maxcap), lambda b: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, N), lambda b: (b, 0)),
-                pl.BlockSpec((1, N), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, S), lambda b: (b, 0)),
-                pl.BlockSpec((1, S), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-                pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B, N), U.dtype),      # V
-                jax.ShapeDtypeStruct((B, N), itype),        # feas
-                jax.ShapeDtypeStruct((B, 1), itype),        # c_lo
-                jax.ShapeDtypeStruct((B, 1), itype),        # c_hi
-                jax.ShapeDtypeStruct((B, S), U.dtype),      # load
-                jax.ShapeDtypeStruct((B, S), itype),        # cnt
-                jax.ShapeDtypeStruct((B, 1), itype),        # best_srv
-                jax.ShapeDtypeStruct((B, 1), itype),        # has_fit
-            ],
-            compiler_params=CompilerParams(),
+            functools.partial(_pool_kernel, load_rel=load_rel),
+            grid=(B // ROW_BLOCK,),
+            in_specs=[_row_spec(N), _row_spec(1), _row_spec(1),
+                      _row_spec(1), _row_spec(1), _whole_spec((N, S))],
+            out_specs=[_row_spec(1), _row_spec(1), _row_spec(S),
+                       _row_spec(1), _row_spec(1), _row_spec(1),
+                       _row_spec(1)],
+            out_shape=[col, col, jax.ShapeDtypeStruct((B, S), jnp.float32),
+                       col, col, col, col],
+            compiler_params=_PARALLEL,
             interpret=interpret,
-        )(U, tlo[:, None], thi[:, None], rho_u[:, None],
-          jnp.reshape(G, (1, 1)).astype(itype), block_idx,
-          block_valid.astype(itype))
-        V, _feas, c_lo2, c_hi2, load, cnt, best2, fit2 = outs
-        c_lo, c_hi = c_lo2[:, 0], c_hi2[:, 0]
-        best_srv, has_fit = best2[:, 0], fit2[:, 0].astype(bool)
+        )(U, t_lo, t_hi, rho_u, G, member)
     else:
-        V, _feas, c_lo, c_hi, load, cnt, best_srv, has_fit = _pool_row_math(
-            U, tlo, thi, rho_u, G, block_idx, block_valid)
-    # feas is recomputed host-side from V (one elementwise compare).
-    return V, c_lo, c_hi, load, cnt, best_srv, has_fit
+        outs = _pool_row_math(U, t_lo, t_hi, rho_u, G, member,
+                              load_rel=load_rel)
+    c_lo, c_hi, load, best, fit, pool_u, fa_u = outs
+    key = load / caps
+    # LBSGF screen.  The picker uses only the least-loaded prefix of
+    # servers whose capacity before them is < lambda*G (``need``), in
+    # stable key order; it is the float64 one when no prefix server's
+    # key lies within the summed bounds of any other server's (an exact
+    # tie of two zero loads is exact in both precisions).
+    a, b = key[:, :, None], key[:, None, :]
+    sid = jnp.arange(S)
+    before = (b < a) | ((b == a) & (sid[None, :] < sid[:, None])[None])
+    in_prefix = jnp.sum(jnp.where(before, caps[:, None, :], 0.0),
+                        axis=2) < need
+    close = (jnp.abs(a - b) <= load_rel * (a + b)) & ~((a == 0) & (b == 0))
+    close = close & (sid[:, None] != sid[None, :])[None]
+    lb_u = jnp.any(close & (in_prefix[:, :, None] | in_prefix[:, None, :]),
+                   axis=(1, 2))
+    unsure = (pool_u[:, 0] > 0) | jnp.where(pid == 0, fa_u[:, 0] > 0, lb_u)
+    return c_lo[:, 0], c_hi[:, 0], key, best[:, 0], fit[:, 0], unsure
 
 
 @functools.partial(jax.jit, static_argnames=(
     "hetero", "b_inter", "b_intra", "use_kernel", "interpret"))
-def _score_probes_jit(Y, f, gamma, scalars, speed_floor, uplink_sh,
+def _score_probes_jit(Y, f, gamma, scal, speed_floor, uplink_sh,
                       uplink_iso, *, hetero, b_inter, b_intra, use_kernel,
                       interpret):
-    """One fused program: Eq. (6)-(8) tau + rho for a candidate batch.
-
-    ``scalars`` is the ``[1, 5]`` job-scalar row (two_share, share,
-    reduce_const, compute, iters), precomputed on the host together with
-    the degradation ``f`` and gamma terms (see :func:`_score_row_math` on
-    why those multiplies must not live inside the program)."""
+    """One program: Eq. (6)-(8) rho-hat slots + screen for a candidate
+    batch.  ``f``/``gamma`` come from the host (see :func:`score_probes`)."""
     B, S = Y.shape
+    kw = dict(hetero=hetero, b_inter=b_inter, b_intra=b_intra)
     if not use_kernel:
-        return _score_row_math(
-            Y, f, gamma, scalars[0, 0], scalars[0, 1], scalars[0, 2],
-            scalars[0, 3], scalars[0, 4], speed_floor[None, :],
-            uplink_sh[None, :], uplink_iso[None, :], hetero=hetero,
-            b_inter=b_inter, b_intra=b_intra)
-    ftype = f.dtype                 # float; Y itself is the int occupancy
-    tau2, rho2 = pl.pallas_call(
-        functools.partial(_score_kernel, hetero=hetero, b_inter=b_inter,
-                          b_intra=b_intra),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 5), lambda b: (0, 0)),
-            pl.BlockSpec((1, S), lambda b: (0, 0)),
-            pl.BlockSpec((1, S), lambda b: (0, 0)),
-            pl.BlockSpec((1, S), lambda b: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), ftype),
-            jax.ShapeDtypeStruct((B, 1), ftype),
-        ],
-        compiler_params=CompilerParams(),
-        interpret=interpret,
-    )(Y, f[:, None], gamma[:, None], scalars, speed_floor[None, :],
-      uplink_sh[None, :], uplink_iso[None, :])
-    return tau2[:, 0], rho2[:, 0]
+        rho, unsure = _score_row_math(Y, f, gamma, scal, speed_floor,
+                                      uplink_sh, uplink_iso, **kw)
+    else:
+        col = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+        rho, unsure = pl.pallas_call(
+            functools.partial(_score_kernel, **kw),
+            grid=(B // ROW_BLOCK,),
+            in_specs=[_row_spec(S), _row_spec(1), _row_spec(1),
+                      _whole_spec((1, 8)), _whole_spec((1, S)),
+                      _whole_spec((1, S)), _whole_spec((1, S))],
+            out_specs=[_row_spec(1), _row_spec(1)],
+            out_shape=[col, col],
+            compiler_params=_PARALLEL,
+            interpret=interpret,
+        )(Y, f, gamma, scal, speed_floor, uplink_sh, uplink_iso)
+    return rho[:, 0], unsure[:, 0]
+
+
+# --------------------------------------------------------------------------
+# Host halves (the float64 oracle expressions)
+# --------------------------------------------------------------------------
+
+
+def _pool_stats_host(cluster, U, V, feas, th_hi, G):
+    """float64 ``(c_lo, c_hi, key, best_srv, has_fit)`` -- the NumPy
+    pickers' reductions, in ``np.bincount``'s GPU-id addition order."""
+    from repro.core.columnar import server_sums
+    N = U.shape[1]
+    c_lo = feas.sum(axis=1)
+    c_hi = (V <= th_hi[:, None] + 1e-9).sum(axis=1)
+    load = server_sums(cluster, U)
+    cnt = server_sums(cluster, feas.astype(np.float64)).astype(np.int64)
+    fits = cnt >= G
+    has_fit = fits.any(axis=1)
+    k_fit = np.where(fits, cnt - G, N + 1)
+    k_occ = np.where(fits, -load, np.inf)
+    t1 = k_fit == k_fit.min(axis=1, keepdims=True)
+    k2 = np.where(t1, k_occ, np.inf)
+    t2 = t1 & (k2 == k2.min(axis=1, keepdims=True))
+    key = load / cluster.capacities_array[None, :].astype(np.float64)
+    return c_lo, c_hi, key, t2.argmax(axis=1), has_fit
+
+
+def _score_host(cluster, job, Y, p):
+    """float64 rho-hat slots (``scalar_tau_many`` + ``slots_for_many``)."""
+    from repro.core import contention as ct
+    n_srv = (Y > 0).sum(axis=1)
+    if cluster.is_heterogeneous:
+        tau = ct.scalar_tau_many(cluster, job, p, n_srv,
+                                 *ct._hetero_mins(cluster, Y > 0))
+    else:
+        tau = ct.scalar_tau_many(cluster, job, p, n_srv)
+    return ct.slots_for_many(job.iters, tau)
 
 
 # --------------------------------------------------------------------------
@@ -355,64 +409,57 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
     charge and ``pid`` its picker id (0 = FA-FFP, 1 = LBSGF).  Returns
     NumPy ``(V, c_lo, c_hi, order, ok)``: the charged clocks, pool counts
     at both extremes, each row's full stable GPU ordering (the pick is
-    ``order[i, :G_j]``) and the pool-large-enough flag -- all bit-identical
-    to the NumPy ``pick_many`` forms under x64.
+    ``order[i, :G_j]``) and the pool-large-enough flag -- equal to the
+    NumPy ``pick_many`` forms on every row (screened rows are recomputed
+    on the host; see the module docstring).
 
-    The device program computes the reductions (pools, per-server busy
-    sums/counts, FA-FFP best server); the stable rankings run here on the
-    host with NumPy's sorts over those bitwise-equal keys, mirroring the
-    second halves of ``_fa_ffp_many`` / ``_lbsgf_many`` term for term.
-    Batches under :data:`DISPATCH_MIN_ROWS` skip the device round-trip and
-    compute the same reductions in NumPy (identical accumulation order via
-    :func:`repro.core.columnar.server_sums`) -- on CPU a dispatch costs
-    more than the stats it replaces below that size.
+    The device program computes the reductions; the stable rankings run
+    here with NumPy's sorts, mirroring the second halves of
+    ``_fa_ffp_many`` / ``_lbsgf_many`` term for term.  On CPU, batches
+    under :data:`DISPATCH_MIN_ROWS` skip the device round-trip.
     """
-    require_x64()
     nw, N = U_stack.shape
     G = job.num_gpus
     consts = _cluster_consts(cluster)
     gpu_server = consts["np_gpu_server"]
     caps = consts["np_caps"]
     S = caps.shape[0]
-    if use_kernel or nw >= DISPATCH_MIN_ROWS:
+    V = U_stack + rho_u[:, None]
+    feas = V <= th_lo[:, None] + 1e-9                  # Eq. (16) pool
+    if use_kernel or nw >= min_dispatch_rows():
         R = _bucket(nw)
-        if R != nw:
-            U_pad = np.concatenate(
-                [U_stack, np.zeros((R - nw, N), dtype=U_stack.dtype)])
-            pad = np.zeros(R - nw)
-            tl, th, ru = (np.concatenate([a, pad])
-                          for a in (th_lo, th_hi, rho_u))
-        else:
-            U_pad, tl, th, ru = U_stack, th_lo, th_hi, rho_u
-        if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        # NumPy operands go straight into the jitted call -- the pjit C++
-        # dispatch converts them far cheaper than an eager device_put
-        # per arg.
-        outs = _pool_stats_jit(
-            U_pad, tl, th, ru, G, consts["block_idx"],
-            consts["block_valid"], use_kernel=use_kernel,
-            interpret=interpret)
-        V, c_lo, c_hi, load, _cnt, best_srv, has_fit = (
+        f32 = np.float32
+
+        def col(a, dtype=f32):
+            return _pad_rows(np.asarray(a).astype(dtype)[:, None], R)
+
+        with jax.enable_x64(False):
+            outs = _pool_stats_jit(
+                _pad_rows(U_stack.astype(f32), R), col(th_lo + 1e-9),
+                col(th_hi + 1e-9), col(rho_u), col(np.full(nw, G), np.int32),
+                _pad_rows(np.asarray(pid, dtype=np.int32), R),
+                # cum < lambda*G over integer capacities == cum < ceil(.)
+                np.float32(math.ceil(job.lam * G)),
+                consts["member"], consts["caps"],
+                load_rel=consts["load_rel"], use_kernel=use_kernel,
+                interpret=_interpret(interpret))
+        c_lo, c_hi, key, best_srv, has_fit, unsure = (
             np.asarray(o)[:nw] for o in outs)
-        feas = V <= th_lo[:, None] + 1e-9              # Eq. (16) pool
+        c_lo, c_hi = c_lo.astype(np.int64), c_hi.astype(np.int64)
+        key, has_fit = key.astype(np.float64), has_fit.astype(bool)
+        best_srv = best_srv.astype(np.int64)
+        redo = np.flatnonzero(unsure)
+        DISPATCH_COUNTS["device"] += 1
+        DISPATCH_COUNTS["rows"] += nw
+        if redo.size:
+            DISPATCH_COUNTS["rechecked"] += redo.size
+            (c_lo[redo], c_hi[redo], key[redo], best_srv[redo],
+             has_fit[redo]) = _pool_stats_host(
+                cluster, U_stack[redo], V[redo], feas[redo], th_hi[redo], G)
     else:
-        from repro.core.columnar import server_sums
-        V = U_stack + rho_u[:, None]
-        feas = V <= th_lo[:, None] + 1e-9              # Eq. (16) pool
-        c_lo = feas.sum(axis=1)
-        c_hi = (V <= th_hi[:, None] + 1e-9).sum(axis=1)
-        load = server_sums(cluster, U_stack)
-        cnt = server_sums(cluster,
-                          feas.astype(np.float64)).astype(np.int64)
-        fits = cnt >= G
-        has_fit = fits.any(axis=1)
-        k_fit = np.where(fits, cnt - G, N + 1)
-        k_occ = np.where(fits, -load, np.inf)
-        t1 = k_fit == k_fit.min(axis=1, keepdims=True)
-        k2 = np.where(t1, k_occ, np.inf)
-        t2 = t1 & (k2 == k2.min(axis=1, keepdims=True))
-        best_srv = t2.argmax(axis=1)
+        DISPATCH_COUNTS["host"] += 1
+        c_lo, c_hi, key, best_srv, has_fit = _pool_stats_host(
+            cluster, U_stack, V, feas, th_hi, G)
     U = U_stack
     order = np.empty((nw, N), dtype=np.int64)
     ok = np.empty(nw, dtype=bool)
@@ -431,8 +478,7 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
         # LBSGF: least-busy server prefix of lambda_j*G capacity, then
         # server-rank-major / least-U lexsort (== _lbsgf_many).
         nl = lb.size
-        srv_order = np.argsort(load[lb] / caps[None, :].astype(np.float64),
-                               axis=1, kind="stable")
+        srv_order = np.argsort(key[lb], axis=1, kind="stable")
         cum = np.cumsum(np.take_along_axis(
             np.broadcast_to(caps[None, :], srv_order.shape), srv_order,
             axis=1), axis=1)
@@ -454,55 +500,57 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
 
 
 def score_probes(cluster, job, Y: np.ndarray, p: np.ndarray, *,
-                 use_kernel: bool = False, interpret: bool | None = None):
-    """Fused Eq. (6)-(8) scoring of one step's probed candidates.
+                 use_kernel: bool = False, interpret: bool | None = None
+                 ) -> np.ndarray:
+    """Fused Eq. (6)-(8) rho-hat slot counts of one step's probed
+    candidates.
 
     ``Y`` [C, S] holds each candidate's occupancy row and ``p`` its
-    host-probed contention level (float64, from the incremental engine's
-    suffix counts).  Returns NumPy ``(tau, rho)`` bit-identical to
-    ``scalar_tau_many`` + ``slots_for_many`` under x64; heterogeneous
-    clusters price worst-member device terms exactly like
-    :func:`repro.core.contention._hetero_mins`.  Batches under
-    :data:`DISPATCH_MIN_ROWS` skip the device round-trip and score through
-    those NumPy forms directly (same expressions, same order).
+    host-probed contention level.  Returns float64 ``rho`` [C] equal to
+    ``slots_for_many(job.iters, scalar_tau_many(...))`` on every row
+    (screened rows are recomputed on the host); heterogeneous clusters
+    price worst-member device terms like
+    :func:`repro.core.contention._hetero_mins`.  On CPU, batches under
+    :data:`DISPATCH_MIN_ROWS` score through the NumPy forms directly.
     """
-    require_x64()
     C, S = Y.shape
-    if not use_kernel and C < DISPATCH_MIN_ROWS:
-        from repro.core import contention as ct
-        n_srv = (Y > 0).sum(axis=1)
-        if cluster.is_heterogeneous:
-            tau = ct.scalar_tau_many(cluster, job, p, n_srv,
-                                     *ct._hetero_mins(cluster, Y > 0))
-        else:
-            tau = ct.scalar_tau_many(cluster, job, p, n_srv)
-        return tau, ct.slots_for_many(job.iters, tau)
+    p = np.asarray(p, dtype=np.float64)
+    if not use_kernel and C < min_dispatch_rows():
+        DISPATCH_COUNTS["host"] += 1
+        return _score_host(cluster, job, Y, p)
     from repro.core.contention import degradation
-    B = _bucket(C)
-    # Host-side contention terms (every multiply that would feed an
-    # addition in-program; see _score_row_math).
-    k = np.maximum(cluster.xi1 * np.asarray(p, dtype=np.float64), 1.0)
-    f = degradation(cluster.alpha, k)
+    if not job.iters < 2 ** 24:
+        raise ValueError(f"iters={job.iters} is not exact in float32")
+    R = _bucket(C)
+    f32 = np.float32
+    # The contention terms that take integer p/n_srv in (k, f, gamma) are
+    # evaluated on the host in float64 and rounded once.
+    f = degradation(cluster.alpha, np.maximum(cluster.xi1 * p, 1.0))
     gamma = cluster.xi2 * (Y > 0).sum(axis=1).astype(np.float64)
-    if B != C:
-        Y = np.concatenate([Y, np.zeros((B - C, S), dtype=Y.dtype)])
-        f = np.concatenate([f, np.ones(B - C)])
-        gamma = np.concatenate([gamma, np.zeros(B - C)])
-    consts = _cluster_consts(cluster)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     w = float(job.num_gpus)
     share = (job.grad_size / w) * (w - 1.0) if w > 1 else 0.0
     compute = job.dt_fwd * float(job.batch) + job.dt_bwd
-    scalars = np.array([[2.0 * share, share, share / cluster.gpu_speed,
-                         compute, float(job.iters)]])
-    tau, rho = _score_probes_jit(
-        Y, f, gamma, scalars, consts["speed_floor"],
-        consts["uplink_shared"], consts["uplink_isolated"],
-        hetero=cluster.is_heterogeneous, b_inter=cluster.b_inter,
-        b_intra=cluster.b_intra, use_kernel=use_kernel,
-        interpret=interpret)
-    return np.asarray(tau)[:C], np.asarray(rho)[:C]
+    scal = np.zeros((1, 8), dtype=f32)
+    scal[0, :5] = (2.0 * share, share, share / cluster.gpu_speed, compute,
+                   float(job.iters))
+    consts = _cluster_consts(cluster)
+    with jax.enable_x64(False):
+        rho, unsure = _score_probes_jit(
+            _pad_rows(Y.astype(np.int32), R),
+            _pad_rows(f.astype(f32)[:, None], R, 1.0),
+            _pad_rows(gamma.astype(f32)[:, None], R), scal,
+            consts["speed_floor"], consts["uplink_shared"],
+            consts["uplink_isolated"], hetero=cluster.is_heterogeneous,
+            b_inter=float(cluster.b_inter), b_intra=float(cluster.b_intra),
+            use_kernel=use_kernel, interpret=_interpret(interpret))
+    rho = np.asarray(rho)[:C].astype(np.float64)
+    redo = np.flatnonzero(np.asarray(unsure)[:C])
+    DISPATCH_COUNTS["device"] += 1
+    DISPATCH_COUNTS["rows"] += C
+    if redo.size:
+        DISPATCH_COUNTS["rechecked"] += redo.size
+        rho[redo] = _score_host(cluster, job, Y[redo], p[redo])
+    return rho
 
 
 def compile_counts() -> dict[str, int]:

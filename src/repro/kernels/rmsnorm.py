@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):

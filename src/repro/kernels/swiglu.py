@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams
 
 
 def _swiglu_kernel(x_ref, wg_ref, wu_ref, o_ref, accg_ref, accu_ref, *,

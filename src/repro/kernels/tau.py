@@ -1,29 +1,20 @@
-"""Jitted Pallas kernel for the Eq. (6)-(8) candidate-stack reduction.
+"""Pallas kernel for the integer half of the Eq. (6)-(8) stack reduction.
 
 The contention model's hot loop scores stacks of candidate placements
 Y [C, J, S]: per candidate, the straddle matrix (Eq. 6), the per-server
 straddler counts, each job's contention level p (a max over its straddled
-servers), and the per-iteration RAR time tau (Eq. 8).  The NumPy pipeline
-in :func:`repro.core.contention.stack_model` materialises several [C, J, S]
-temporaries in host memory; this kernel fuses the whole reduction into one
-VMEM pass per candidate -- one grid step per candidate row, straddle/count/
-max/tau on the VPU, no host round-trips between the stages.
+servers) and its server spread n_srv.  Those are the O(C J S) part of
+:func:`repro.core.contention.stack_model`; this kernel fuses them into one
+VMEM pass per candidate in int32, where they are exact.  The O(C J)
+Eq. (7)-(8) float terms built on (p, n_srv) stay in float64 on the host,
+so the model the caller gets back -- tau, phi and every term -- is the
+NumPy engine's to the bit (the TPU has no IEEE float64).  Heterogeneous
+clusters change only those host float terms, so one kernel serves both.
 
-On CPU the kernel runs in Pallas interpret mode and exists for numerics
-parity and TPU forward-compat, not speed (the interpreter is an emulator);
-it is therefore opt-in via :func:`repro.core.contention.tau_backend`.  With
-``jax_enable_x64`` the arithmetic is float64 in the same operation order as
-the NumPy engines, so the results are bit-identical (pinned by
-``tests/test_kernels.py``); without x64 jax computes in float32 and the
-kernel is only approximately equal.
-
-This kernel scores *given* candidate stacks; its sibling
-:mod:`repro.kernels.placement` fuses the columnar placement engine's
-per-step reductions (FA-FFP/LBSGF pick stats over branch rows, Eq.
-(15)/(16) busy-time pools, refined-rho scoring) the same way -- same
-grid-per-row layout, same x64 bit-identity contract, plus plain
-``jax.jit`` variants that are the CPU fast path where the interpret-mode
-Pallas lowering is the parity artifact.
+On CPU the kernel runs in Pallas interpret mode (opt-in via
+:func:`repro.core.contention.tau_backend`); on TPU it lowers through
+Mosaic.  Its sibling :mod:`repro.kernels.placement` fuses the columnar
+placement engine's per-step reductions the same way.
 """
 from __future__ import annotations
 
@@ -33,196 +24,58 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
-
-def _tau_kernel_het(y_ref, g_ref, share_ref, compute_ref, spd_ref, sh_ref,
-                    iso_ref, p_ref, n_ref, tau_ref, *, xi1: float,
-                    xi2: float, alpha: float, b_intra: float):
-    """Heterogeneous candidate: Y [1, J, S] + per-server device terms
-    [1, S] -> p/n_srv/tau [1, J].
-
-    ``spd_ref``/``sh_ref``/``iso_ref`` hold the cluster's server speed
-    floors and shared/isolated uplink bandwidths (+inf where the class is
-    absent); the kernel reduces each row's worst members in VMEM with the
-    same masked-min selections as ``contention._hetero_mins`` and prices
-    Eq. (8) with ``min(bw_iso, bw_sh / f)`` -- isolated uplinks skip the
-    sharing divisor."""
-    y = y_ref[0]                                     # [J, S]
-    g = g_ref[0]                                     # [J]
+def _counts_kernel(y_ref, g_ref, p_ref, n_ref):
+    """One candidate: Y [1, J, S], G [1, J, 1] -> p, n_srv [1, J, 1]."""
+    y = y_ref[...]
     pos = y > 0
-    straddle = pos & (y < g[:, None])                # Eq. (6) straddling
-    per_server = jnp.sum(straddle.astype(y.dtype), axis=0)        # [S]
-    p = jnp.max(jnp.where(straddle, per_server[None, :], 0), axis=1)
-    n_srv = jnp.sum(pos.astype(y.dtype), axis=1)
-    ftype = tau_ref.dtype
-    inf = jnp.asarray(jnp.inf, dtype=ftype)
-    speed = jnp.min(jnp.where(pos, spd_ref[0][None, :], inf), axis=1)
-    bw_sh = jnp.min(jnp.where(pos, sh_ref[0][None, :], inf), axis=1)
-    bw_iso = jnp.min(jnp.where(pos, iso_ref[0][None, :], inf), axis=1)
-    k = jnp.maximum(xi1 * p.astype(ftype), 1.0)      # Eq. (7)
-    f = k + alpha * (k - 1.0)                        # degradation f(a, k)
-    bandwidth = jnp.where(n_srv > 1, jnp.minimum(bw_iso, bw_sh / f), b_intra)
-    gamma = xi2 * n_srv.astype(ftype)
-    exchange = 2.0 * share_ref[0] / bandwidth
-    # Eq. (8), same left-to-right addition order as the NumPy engines.
-    tau_ref[0] = exchange + share_ref[0] / speed + gamma + compute_ref[0]
-    p_ref[0] = p
-    n_ref[0] = n_srv
+    straddle = pos & (y < g_ref[...])                # Eq. (6) straddling
+    per_server = jnp.sum(straddle.astype(jnp.int32), axis=1, keepdims=True)
+    p_ref[...] = jnp.max(jnp.where(straddle, per_server, 0), axis=2,
+                         keepdims=True)
+    n_ref[...] = jnp.sum(pos.astype(jnp.int32), axis=2, keepdims=True)
 
 
-def _tau_kernel(y_ref, g_ref, share_ref, reduce_ref, compute_ref,
-                p_ref, n_ref, tau_ref, *, xi1: float, xi2: float,
-                alpha: float, b_inter: float, b_intra: float):
-    """One candidate: Y [1, J, S] -> p/n_srv/tau [1, J]."""
-    y = y_ref[0]                                     # [J, S]
-    g = g_ref[0]                                     # [J]
-    pos = y > 0
-    straddle = pos & (y < g[:, None])                # Eq. (6) straddling
-    per_server = jnp.sum(straddle.astype(y.dtype), axis=0)        # [S]
-    p = jnp.max(jnp.where(straddle, per_server[None, :], 0), axis=1)
-    n_srv = jnp.sum(pos.astype(y.dtype), axis=1)
-    ftype = tau_ref.dtype
-    k = jnp.maximum(xi1 * p.astype(ftype), 1.0)      # Eq. (7)
-    f = k + alpha * (k - 1.0)                        # degradation f(a, k)
-    bandwidth = jnp.where(n_srv > 1, b_inter / f, b_intra)
-    gamma = xi2 * n_srv.astype(ftype)
-    exchange = 2.0 * share_ref[0] / bandwidth
-    # Eq. (8), same left-to-right addition order as the NumPy engines.
-    tau_ref[0] = exchange + reduce_ref[0] + gamma + compute_ref[0]
-    p_ref[0] = p
-    n_ref[0] = n_srv
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "xi1", "xi2", "alpha", "b_inter", "b_intra", "gpu_speed", "terms_2d",
-    "interpret"))
-def _tau_stack_jit(Y, G, share, compute, *, xi1, xi2, alpha, b_inter,
-                   b_intra, gpu_speed, terms_2d, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _stack_counts_jit(Y, G, *, interpret):
+    """``Y`` [C, J, S] int32, ``G`` [C, J, 1] or [1, J, 1] int32."""
     C, J, S = Y.shape
-    ftype = share.dtype
-    itype = Y.dtype
-    reduce_t = share / gpu_speed
-    # Shared [J] terms pin every grid step to block (0, 0); per-candidate
-    # [C, J] terms ride the same grid axis as the Y stack -- the branch
-    # axis of the columnar placement engine IS the kernel grid dimension.
-    term_idx = (lambda c: (c, 0)) if terms_2d else (lambda c: (0, 0))
-    return pl.pallas_call(
-        functools.partial(_tau_kernel, xi1=xi1, xi2=xi2, alpha=alpha,
-                          b_inter=b_inter, b_intra=b_intra),
+    # The candidate axis is the grid; blocks span whole (J, S) / (J, 1)
+    # trailing dims, which is tile-legal at any J and S.
+    g_idx = (lambda c: (c, 0, 0)) if G.shape[0] == C else (lambda c: (0, 0, 0))
+    col = jax.ShapeDtypeStruct((C, J, 1), jnp.int32)
+    p, n_srv = pl.pallas_call(
+        _counts_kernel,
         grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, J, S), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, J), term_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((C, J), itype),     # p
-            jax.ShapeDtypeStruct((C, J), itype),     # n_srv
-            jax.ShapeDtypeStruct((C, J), ftype),     # tau
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        in_specs=[pl.BlockSpec((1, J, S), lambda c: (c, 0, 0)),
+                  pl.BlockSpec((1, J, 1), g_idx)],
+        out_specs=[pl.BlockSpec((1, J, 1), lambda c: (c, 0, 0))] * 2,
+        out_shape=[col, col],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(Y, G if terms_2d else G[None, :],
-      share if terms_2d else share[None, :],
-      reduce_t if terms_2d else reduce_t[None, :],
-      compute if terms_2d else compute[None, :])
+    )(Y, G)
+    return p[:, :, 0], n_srv[:, :, 0]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "xi1", "xi2", "alpha", "b_intra", "terms_2d", "interpret"))
-def _tau_stack_het_jit(Y, G, share, compute, spd, sh, iso, *, xi1, xi2,
-                       alpha, b_intra, terms_2d, interpret):
-    C, J, S = Y.shape
-    ftype = share.dtype
-    itype = Y.dtype
-    term_idx = (lambda c: (c, 0)) if terms_2d else (lambda c: (0, 0))
-    # The [1, S] device-term rows are grid-invariant: every candidate
-    # reads block (0, 0).
-    srv_idx = lambda c: (0, 0)  # noqa: E731 - BlockSpec index lambda
-    return pl.pallas_call(
-        functools.partial(_tau_kernel_het, xi1=xi1, xi2=xi2, alpha=alpha,
-                          b_intra=b_intra),
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, J, S), lambda c: (c, 0, 0)),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, J), term_idx),
-            pl.BlockSpec((1, S), srv_idx),
-            pl.BlockSpec((1, S), srv_idx),
-            pl.BlockSpec((1, S), srv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-            pl.BlockSpec((1, J), lambda c: (c, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((C, J), itype),     # p
-            jax.ShapeDtypeStruct((C, J), itype),     # n_srv
-            jax.ShapeDtypeStruct((C, J), ftype),     # tau
-        ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(Y, G if terms_2d else G[None, :],
-      share if terms_2d else share[None, :],
-      compute if terms_2d else compute[None, :],
-      spd[None, :], sh[None, :], iso[None, :])
+def stack_counts(G: np.ndarray, Y: np.ndarray,
+                 interpret: bool | None = None
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """Kernel-backed Eq. (6) reduction: ``(p, n_srv)``, int64 [C, J].
 
-
-def tau_stack(cluster, G: np.ndarray, share: np.ndarray,
-              compute: np.ndarray, Y: np.ndarray,
-              interpret: bool | None = None
-              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Kernel-backed Eq. (6)-(8) stack reduction: (p, n_srv, tau), [C, J].
-
-    ``Y`` [C, J, S] is the (already masked) candidate stack; ``G``,
-    ``share`` and ``compute`` are the placement-independent per-job terms
-    (see ``repro.core.contention._job_terms``), either shared across the
-    stack ([J]) or per-candidate ([C, J], the columnar branch-stack
-    layout, in which case the candidate/branch axis becomes the kernel's
-    grid dimension for the term blocks too).  ``interpret`` defaults to
-    Pallas interpret mode on CPU backends.
-
-    Heterogeneous clusters dispatch to a kernel variant that carries the
-    per-server speed floors and shared/isolated uplink bandwidths as
-    grid-invariant [1, S] operands and reduces each row's worst members
-    in VMEM (see :func:`_tau_kernel_het`); homogeneous clusters keep the
-    original static-scalar kernel.
-    """
+    ``Y`` [C, J, S] is the (already masked) candidate stack and ``G`` the
+    per-job GPU counts, shared across the stack ([J]) or per candidate
+    ([C, J], the columnar branch-stack layout).  ``interpret`` defaults to
+    Pallas interpret mode on CPU backends."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     G = np.asarray(G)
     if G.ndim not in (1, 2):
         raise ValueError(f"G must be [J] or [C, J], got shape {G.shape}")
-    itype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    ftype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    if cluster.is_heterogeneous:
-        p, n_srv, tau = _tau_stack_het_jit(
-            jnp.asarray(Y, dtype=itype), jnp.asarray(G, dtype=itype),
-            jnp.asarray(share, dtype=ftype), jnp.asarray(compute, dtype=ftype),
-            jnp.asarray(cluster.server_speed_floor, dtype=ftype),
-            jnp.asarray(cluster.uplink_shared_or_inf, dtype=ftype),
-            jnp.asarray(cluster.uplink_isolated_or_inf, dtype=ftype),
-            xi1=float(cluster.xi1), xi2=float(cluster.xi2),
-            alpha=float(cluster.alpha), b_intra=float(cluster.b_intra),
-            terms_2d=G.ndim == 2, interpret=bool(interpret))
-    else:
-        p, n_srv, tau = _tau_stack_jit(
-            jnp.asarray(Y, dtype=itype), jnp.asarray(G, dtype=itype),
-            jnp.asarray(share, dtype=ftype), jnp.asarray(compute, dtype=ftype),
-            xi1=float(cluster.xi1), xi2=float(cluster.xi2),
-            alpha=float(cluster.alpha), b_inter=float(cluster.b_inter),
-            b_intra=float(cluster.b_intra), gpu_speed=float(cluster.gpu_speed),
-            terms_2d=G.ndim == 2, interpret=bool(interpret))
-    return (np.asarray(p, dtype=np.int64), np.asarray(n_srv, dtype=np.int64),
-            np.asarray(tau, dtype=np.float64))
+    G3 = (G if G.ndim == 2 else G[None, :])[:, :, None].astype(np.int32)
+    with jax.enable_x64(False):
+        p, n_srv = _stack_counts_jit(np.asarray(Y, dtype=np.int32), G3,
+                                     interpret=bool(interpret))
+    return np.asarray(p, dtype=np.int64), np.asarray(n_srv, dtype=np.int64)

@@ -35,7 +35,7 @@ from repro.configs import (INPUT_SHAPES, ARCHS, cache_slots, get_config,
 from repro.dist import sharding as shd
 from repro.dist.steps import make_serve_step, make_train_step
 from repro.launch import roofline
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import TARGET_KIND, make_production_mesh
 from repro.models import build_model
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
@@ -126,7 +126,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
         hlo_flops=flops, hlo_bytes=byts,
         collective_bytes=stats.total_bytes, collectives=stats,
         model_flops=roofline.model_step_flops(cfg, shape),
-        per_device_hbm_peak=mem)
+        per_device_hbm_peak=mem, device_kind=TARGET_KIND)
     row = rl.row()
     row["compile_s"] = round(t1 - t0, 1)
     row["collective_counts"] = stats.count_by_kind

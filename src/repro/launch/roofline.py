@@ -1,10 +1,12 @@
 """Roofline-term extraction from compiled XLA artifacts.
 
-Three terms per (arch, shape, mesh), in seconds (v5e constants):
+Three terms per (arch, shape, mesh), in seconds, from the peak rates of
+the target chip (``repro.launch.mesh.peak_rates``, keyed by device kind;
+v5e: 197e12 FLOP/s, 819e9 B/s HBM, 50e9 B/s per ICI link):
 
-  compute    = HLO_FLOPs            / (chips * 197e12)
-  memory     = HLO_bytes            / (chips * 819e9)
-  collective = collective_bytes     / (chips * 50e9)
+  compute    = HLO_FLOPs            / (chips * peak FLOP/s)
+  memory     = HLO_bytes            / (chips * HBM bytes/s)
+  collective = collective_bytes     / (chips * ICI bytes/s)
 
 FLOPs/bytes come from ``compiled.cost_analysis()``.  Collective bytes are
 not in cost_analysis: we parse the post-SPMD HLO text and sum the operand
@@ -21,7 +23,7 @@ import re
 
 import numpy as np
 
-from repro.launch.mesh import DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peak_rates
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -431,21 +433,23 @@ class Roofline:
     collectives: CollectiveStats
     model_flops: float            # 6*N*D (or 6*N_active*D) per step, GLOBAL
     per_device_hbm_peak: float    # from memory_analysis, bytes
+    device_kind: str              # the target chip (keys its peak rates)
 
     @property
     def t_compute(self) -> float:
         # == global_FLOPs / (chips * peak): cost_analysis is already /chip
-        return self.hlo_flops / PEAK_FLOPS_BF16
+        return self.hlo_flops / peak_rates(self.device_kind)["flops_bf16"]
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / peak_rates(self.device_kind)["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
+        peaks = peak_rates(self.device_kind)
         dcn = self.collectives.dcn_bytes if self.collectives else 0.0
         ici = self.collective_bytes - dcn
-        return ici / ICI_BW + dcn / DCN_BW
+        return ici / peaks["ici_bw"] + dcn / peaks["dcn_bw"]
 
     @property
     def bottleneck(self) -> str:

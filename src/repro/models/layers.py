@@ -30,22 +30,22 @@ from repro.models.config import ModelConfig
 def shard_hint(x: jax.Array, *axes) -> jax.Array:
     """with_sharding_constraint that degrades gracefully: each entry of
     ``axes`` is None | axis-name | tuple-of-names; an axis is applied only
-    if it exists in the ambient (abstract) mesh and divides the dim.  On an
-    un-meshed trace (CPU smoke tests) this is the identity, so models stay
-    mesh-agnostic."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:                                   # pragma: no cover
-        return x
+    if it is an ``Auto`` axis of the ambient (abstract) mesh and divides
+    the dim (an ``Explicit`` axis carries its sharding in the array type,
+    where a constraint would be an assert).  On an un-meshed trace (CPU
+    smoke tests) this is the identity, so models stay mesh-agnostic."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
     spec = []
     for dim, ax in zip(x.shape, axes):
         if ax is None:
             spec.append(None)
             continue
         cand = tuple(a for a in ((ax,) if isinstance(ax, str) else ax)
-                     if a in mesh.axis_names)
+                     if a in auto)
         size = 1
         for a in cand:
             size *= mesh.shape[a]

@@ -31,12 +31,13 @@ from repro.configs import get_config, input_specs, INPUT_SHAPES
 from repro.dist import sharding as shd
 from repro.dist.steps import make_serve_step, make_train_step
 from repro.launch import roofline
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.models.config import InputShape
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 """
 
 

@@ -83,6 +83,26 @@ def _random_stack(cluster, jobs, rng, n_cands=5):
     return stack
 
 
+def _feasible_stack(cluster, jobs, rng, n_cands=5):
+    """Random placements that respect every server's capacity (jobs that
+    no longer fit are left inactive) -> (stack, active)."""
+    caps = np.asarray(cluster.capacities)
+    stack = np.zeros((n_cands, len(jobs), cluster.num_servers),
+                     dtype=np.int64)
+    active = np.zeros((n_cands, len(jobs)), dtype=bool)
+    for c in range(n_cands):
+        free = caps.copy()
+        for i, job in enumerate(jobs):
+            if job.num_gpus > free.sum():
+                continue
+            for _ in range(job.num_gpus):
+                s = rng.choice(np.flatnonzero(free > 0))
+                stack[c, i, s] += 1
+                free[s] -= 1
+            active[c, i] = True
+    return stack, active
+
+
 def _assert_schedules_equal(a, b):
     assert a.theta == b.theta
     assert a.kappa == b.kappa
@@ -267,11 +287,16 @@ def _engine_agreement(seed):
         # Probes agree with committed rows.
         probe = inc.probe_tau_many(jobs[0], stack[:, 0, :])
         assert probe.shape == (stack.shape[0],)
-    # tau_bounds brackets every realised tau on the mixed cluster.
+    # tau_bounds brackets every realised tau of a placement that fits the
+    # mixed cluster (its k_max premise: no more jobs share a server than
+    # it has GPUs; the stack above ignores capacities).
+    fstack, active = _feasible_stack(cluster, jobs, rng)
+    fmany = evaluate_many(cluster, jobs, fstack, active=active)
     for i, job in enumerate(jobs):
         lo, hi = tau_bounds(cluster, job)
-        assert float(many.tau[:, i].min()) >= lo
-        assert float(many.tau[:, i].max()) <= hi
+        taus = fmany.tau[active[:, i], i]
+        assert np.all(taus >= lo) and np.all(taus <= hi)
+    assert active.any()
 
 
 class TestHeteroEngineAgreement:
@@ -299,21 +324,19 @@ class TestHeteroEngineAgreement:
             assert taus[c] == inc.probe_tau(jobs[0], cands[c])
 
     def test_kernel_backend_agrees_x64(self):
-        import jax
+        """The int32 kernel + float64 host terms equal the NumPy engine
+        on a mixed cluster (x64 off, as the kernels run)."""
+        pytest.importorskip("jax")
         from repro.core.contention import tau_backend
-        x64_was = jax.config.jax_enable_x64
-        jax.config.update("jax_enable_x64", True)
-        try:
-            cluster, jobs = _hetero_case(3, n_jobs=12)
-            stack = _random_stack(cluster, jobs, np.random.default_rng(3))
-            ref = evaluate_many(cluster, jobs, stack)
-            with tau_backend("kernel"):
-                kern = evaluate_many(cluster, jobs, stack)
-            assert np.array_equal(ref.p, kern.p)
-            assert np.array_equal(ref.tau, kern.tau)
-            assert np.array_equal(ref.phi, kern.phi)
-        finally:
-            jax.config.update("jax_enable_x64", x64_was)
+        cluster, jobs = _hetero_case(3, n_jobs=12)
+        stack = _random_stack(cluster, jobs, np.random.default_rng(3))
+        ref = evaluate_many(cluster, jobs, stack)
+        with tau_backend("kernel"):
+            kern = evaluate_many(cluster, jobs, stack)
+        assert np.array_equal(ref.p, kern.p)
+        assert np.array_equal(ref.tau, kern.tau)
+        assert np.array_equal(ref.phi, kern.phi)
+        assert np.array_equal(ref.bandwidth, kern.bandwidth)
 
 
 class TestDirectedHetero:

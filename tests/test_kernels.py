@@ -159,36 +159,35 @@ class TestTauKernel:
         return cluster, jobs, stack
 
     def test_tau_stack_matches_numpy_f32(self):
-        """Without x64 the kernel computes in float32: approximate."""
+        """The 32-bit kernel's Eq. (6) counts are exact integers."""
         from repro.core.contention import _job_terms, evaluate_many
-        from repro.kernels.tau import tau_stack
+        from repro.kernels.tau import stack_counts
         cluster, jobs, stack = self._case()
         ref_model = evaluate_many(cluster, jobs, stack)
-        G, share, compute = _job_terms(jobs)
-        p, n_srv, tau = tau_stack(cluster, G, share, compute, stack)
+        G, _, _ = _job_terms(jobs)
+        p, n_srv = stack_counts(G, stack)
         assert np.array_equal(p, ref_model.p)       # integer: exact
-        np.testing.assert_allclose(tau, ref_model.tau, rtol=1e-5)
+        assert np.array_equal(n_srv, (stack > 0).sum(axis=2))
+        # Per-candidate [C, J] terms (the columnar branch-stack layout).
+        p2, n2 = stack_counts(np.broadcast_to(G, stack.shape[:2]), stack)
+        assert np.array_equal(p2, p) and np.array_equal(n2, n_srv)
 
     def test_tau_backend_bit_identity_x64(self):
-        """With x64, the kernel path of stack_model / evaluate_many is
-        bit-identical to the NumPy engines (same op order, float64)."""
+        """The kernel path of stack_model / evaluate_many is bit-identical
+        to the NumPy engines: exact int32 counts on the device, Eq. (8)
+        in float64 on the host -- with x64 off, as the kernels run."""
         from repro.core.contention import evaluate, evaluate_many, tau_backend
-        x64_was = jax.config.jax_enable_x64
-        jax.config.update("jax_enable_x64", True)
-        try:
-            cluster, jobs, stack = self._case(seed=3)
-            ref_model = evaluate_many(cluster, jobs, stack)
-            with tau_backend("kernel"):
-                kern = evaluate_many(cluster, jobs, stack)
-            assert np.array_equal(ref_model.p, kern.p)
-            assert np.array_equal(ref_model.tau, kern.tau)
-            assert np.array_equal(ref_model.phi, kern.phi)
-            assert np.array_equal(ref_model.bandwidth, kern.bandwidth)
-            for c in range(stack.shape[0]):
-                per = evaluate(cluster, jobs, stack[c])
-                assert np.array_equal(per.tau, kern.tau[c])
-        finally:
-            jax.config.update("jax_enable_x64", x64_was)
+        cluster, jobs, stack = self._case(seed=3)
+        ref_model = evaluate_many(cluster, jobs, stack)
+        with tau_backend("kernel"):
+            kern = evaluate_many(cluster, jobs, stack)
+        assert np.array_equal(ref_model.p, kern.p)
+        assert np.array_equal(ref_model.tau, kern.tau)
+        assert np.array_equal(ref_model.phi, kern.phi)
+        assert np.array_equal(ref_model.bandwidth, kern.bandwidth)
+        for c in range(stack.shape[0]):
+            per = evaluate(cluster, jobs, stack[c])
+            assert np.array_equal(per.tau, kern.tau[c])
 
     def test_unknown_tau_backend_rejected(self):
         from repro.core.contention import tau_backend

@@ -96,10 +96,11 @@ class TestRingAllReduce:
             batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
                                                   (4, 32), 0, cfg.vocab)}
             mesh = jax.make_mesh((4,), ("data",))
-            rar_step = make_rar_train_step(model, ocfg, mesh)
-            p1, o1, m1 = rar_step(params, opt, batch)
             ref_step = make_train_step(model, ocfg)
             p2, o2, m2 = jax.jit(ref_step)(params, opt, batch)
+            # The RAR step donates params/opt, so it runs second.
+            rar_step = make_rar_train_step(model, ocfg, mesh)
+            p1, o1, m1 = rar_step(params, opt, batch)
             d = max(float(jnp.abs(a - b).max()) for a, b in zip(
                 jax.tree.leaves(p1), jax.tree.leaves(p2)))
             print("LOSS_DIFF", abs(float(m1["loss"]) - float(m2["loss"])))
