@@ -204,13 +204,66 @@ def check_evaluate_many(cluster, hetero_cluster, jobs, seed: int) -> None:
 # --------------------------------------------------------------------------
 
 
-def _max_abs_diff(a, b) -> float:
+# AdamW's first step from zero moments moves a weight by
+# lr * (g / (|g| + eps) + wd * p).  Where the clipped gradient g is well
+# above eps and above the reassociation noise of two ways of summing it
+# -- |g| >= max(100 eps, STRONG_RMS * the leaf's rms gradient) -- the
+# weight moves by lr to within 1%, and two correct steps agree on its
+# update's sign and size.  The noise still flips a few: internvl2-1b's
+# RAR step at w=4 on a TPU v5e disagreed with the single-program step on
+# 1.6e-4 of its strong weights, while a ring that drops the all-reduce
+# disagrees on about 0.3 of them (reduced model, CPU).  MAX_MISMATCH
+# sits between the two.
+STRONG_RMS = 0.1
+UPDATE_TOL = 0.02          # of lr
+MAX_MISMATCH = 1e-3        # share of the strong weights
+
+
+def compare_update(p0, ref, got, ref_moment, ocfg, lr: float) -> dict:
+    """The update ``got - p0`` against the reference update ``ref - p0``
+    (host copies; ``ref_moment`` is the reference step's first moment,
+    ``(1 - b1) * g``).  Counts the weights, those that moved by ``lr``
+    to ``UPDATE_TOL * lr``, the strong ones (gradient well above noise),
+    the strong ones whose update is not such a move agreeing with the
+    reference's to ``UPDATE_TOL * lr``, and the largest update gap over
+    every weight."""
     import jax
-    import jax.numpy as jnp
-    leaves = jax.tree.leaves(jax.tree.map(
-        lambda x, y: jnp.max(jnp.abs(x.astype(jnp.float32)
-                                     - y.astype(jnp.float32))), a, b))
-    return max(float(v) for v in leaves)
+    import numpy as np
+
+    out = {"weights": 0, "moved": 0, "strong": 0, "mismatch": 0,
+           "max_gap": 0.0}
+    for a0, ar, ag, m in zip(*(jax.tree.leaves(t)
+                               for t in (p0, ref, got, ref_moment))):
+        a0, ar, ag = (np.asarray(a, np.float32) for a in (a0, ar, ag))
+        g = np.abs(np.asarray(m, np.float32)) / (1 - ocfg.b1)
+        rms = float(np.sqrt(np.mean(np.square(g, dtype=np.float64))))
+        strong = g >= max(100 * ocfg.eps, STRONG_RMS * rms)
+        gap = np.abs(ag - ar)
+        # |update + lr * wd * p| is the gradient part of the move: lr.
+        move = np.abs(ag - a0 + lr * ocfg.weight_decay * a0)
+        moved = np.abs(move - lr) <= UPDATE_TOL * lr
+        bad = (gap > UPDATE_TOL * lr) | ~moved
+        out["weights"] += a0.size
+        out["moved"] += int(moved.sum())
+        out["strong"] += int(strong.sum())
+        out["mismatch"] += int((bad & strong).sum())
+        out["max_gap"] = max(out["max_gap"], float(gap.max()))
+    return out
+
+
+def check_update(cmp: dict, lr: float, what: str) -> None:
+    """Most weights moved by lr, the strong ones as the reference did;
+    none by more than a sign flip of a near-zero gradient (2 lr) beyond
+    the reference."""
+    check(cmp["moved"] >= 0.5 * cmp["weights"],
+          f"{what}: under half the weights moved by lr")
+    check(cmp["strong"] >= 0.25 * cmp["weights"],
+          f"{what}: under a quarter of the weights have a gradient above "
+          "noise")
+    check(cmp["mismatch"] <= MAX_MISMATCH * cmp["strong"],
+          f"{what}: update differs from the reference step's")
+    check(cmp["max_gap"] <= 2.5 * lr,
+          f"{what}: a weight moved past the reference by over 2 lr")
 
 
 def phase_train(seed: int) -> None:
@@ -228,16 +281,20 @@ def phase_train(seed: int) -> None:
         f"heads={cfg.n_heads}/{cfg.n_kv_heads} d_ff={cfg.d_ff} "
         f"vocab={cfg.vocab}; batch {targs.batch} x seq {targs.seq}")
 
-    # Reference: the single-program step on the same weights and batch.
+    # Reference: the single-program step on the same weights and batch;
+    # it leaves only host copies, so the run has the chip to itself.
     params = model.init(jax.random.PRNGKey(seed))
+    p0 = jax.device_get(params)
     opt = adamw.init(ocfg, params)
     t0 = time.perf_counter()
     ref_params, ref_opt, ref_m = jax.jit(make_train_step(model, ocfg),
                                          donate_argnums=(0, 1))(
         params, opt, train.batch_at(targs, cfg, shape, 0))
-    ref = {"params": ref_params, "loss": float(ref_m["loss"]),
-           "gnorm": float(ref_m["grad_norm"])}
-    del params, opt, ref_params, ref_opt     # free the chip for the run
+    ref = {"params": jax.device_get(ref_params),
+           "moment": jax.device_get(ref_opt["m"]),
+           "loss": float(ref_m["loss"]), "gnorm": float(ref_m["grad_norm"]),
+           "lr": float(ref_m["lr"])}
+    del params, opt, ref_params, ref_opt
     log(f"make_train_step: loss {ref['loss']:.6f} grad_norm "
         f"{ref['gnorm']:.6f} ({time.perf_counter() - t0:.1f}s with compile)")
 
@@ -245,7 +302,7 @@ def phase_train(seed: int) -> None:
 
     def on_step(step, params, metrics):
         if step == 0:
-            seen["pdiff"] = _max_abs_diff(params, ref.pop("params"))
+            seen["params"] = jax.device_get(params)
             seen["loss"] = float(metrics["loss"])
             seen["gnorm"] = float(metrics["grad_norm"])
 
@@ -255,21 +312,21 @@ def phase_train(seed: int) -> None:
     losses = out["losses"]
     stats = jax.devices()[0].memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
+    cmp = compare_update(p0, ref["params"], seen["params"], ref["moment"],
+                         ocfg, ref["lr"])
     log(f"RAR w=1 step 0: loss {seen['loss']:.6f} grad_norm "
-        f"{seen['gnorm']:.6f}; max |param - ref| {seen['pdiff']:.3e}; "
+        f"{seen['gnorm']:.6f}; update vs ref: {cmp} (lr {ref['lr']:.3e}); "
         f"losses {losses}; {dt:.1f}s with compile; peak device memory "
         f"{peak} bytes")
     check(all(math.isfinite(v) for v in losses), "non-finite loss")
     # Same graph up to fusion order: the loss and the gradient norm agree
     # to f32 reassociation of bf16-compute partial sums (1e-3 / 1e-2
-    # relative); Adam's first step moves each weight by at most ~lr, so a
-    # sign flip of a near-zero gradient bounds the parameter gap by 2 lr.
+    # relative).
     check(abs(seen["loss"] - ref["loss"]) <= 1e-3 * abs(ref["loss"]),
           "RAR loss differs from make_train_step")
     check(abs(seen["gnorm"] - ref["gnorm"]) <= 1e-2 * ref["gnorm"],
           "RAR grad norm differs from make_train_step")
-    check(seen["pdiff"] <= 2.5 * ocfg.lr,
-          "RAR update differs from make_train_step")
+    check_update(cmp, ref["lr"], "RAR w=1")
 
 
 # --------------------------------------------------------------------------
@@ -336,7 +393,6 @@ def phase_rar_step(seed: int) -> None:
     from functools import partial
 
     import jax
-    import numpy as np
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -359,10 +415,12 @@ def phase_rar_step(seed: int) -> None:
 
     # The reference runs first (on one chip) and leaves only host copies,
     # so the RAR step has each chip's memory to itself.
+    p0 = jax.device_get(jax.jit(model.init)(jax.random.PRNGKey(seed)))
     ref_p, ref_opt, ref_m = jax.jit(make_train_step(model, ocfg),
                                     donate_argnums=(0, 1))(*fresh(), batch)
-    ref_p, ref_loss = jax.device_get(ref_p), float(ref_m["loss"])
-    ref_gn = float(ref_m["grad_norm"])
+    ref_p, ref_moment = jax.device_get((ref_p, ref_opt["m"]))
+    ref_loss, ref_gn = float(ref_m["loss"]), float(ref_m["grad_norm"])
+    lr = float(ref_m["lr"])
     del ref_opt
     mesh = make_mesh((w,), ("data",), devices=jax.devices()[:w])
     log(f"RAR step mesh device ids {[d.id for d in mesh.devices.flat]}")
@@ -372,22 +430,20 @@ def phase_rar_step(seed: int) -> None:
     p = jax.device_get(p)
     dl = abs(float(m["loss"]) - ref_loss)
     dg = abs(float(m["grad_norm"]) - ref_gn)
-    dp = max(float(np.max(np.abs(a - b))) for a, b in zip(
-        jax.tree.leaves(p), jax.tree.leaves(ref_p)))
+    cmp = compare_update(p0, ref_p, p, ref_moment, ocfg, lr)
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
              for d in mesh.devices.flat]
     log(f"RAR w={w} vs make_train_step on the concatenated batch "
         f"({cfg.name}, batch {targs.batch} x {targs.seq}): loss "
         f"{ref_loss:.6f}, |loss gap| {dl:.3e}, |grad-norm gap| {dg:.3e}, "
-        f"max |param gap| {dp:.3e}; peak device memory per chip {peaks}")
+        f"update vs ref: {cmp} (lr {lr:.3e}); peak device memory per chip "
+        f"{peaks}")
     # Per-shard losses and gradients summed in ring order against one
     # fused program: f32 reassociation of bf16-compute partial sums
-    # (1e-3 / 1e-2 relative); Adam's first step moves each weight by at
-    # most ~lr, so a sign flip of a near-zero gradient bounds the
-    # parameter gap by 2 lr.
+    # (1e-3 / 1e-2 relative).
     check(dl <= 1e-3 * abs(ref_loss), "RAR loss differs")
     check(dg <= 1e-2 * ref_gn, "RAR grad norm differs")
-    check(dp <= 2.5 * ocfg.lr, "RAR-DP diverged from the reference step")
+    check_update(cmp, lr, f"RAR w={w}")
 
 
 def phase_sched_launch(seed: int) -> None:
@@ -396,9 +452,9 @@ def phase_sched_launch(seed: int) -> None:
 
     from repro.launch import sched_launch
 
-    records = sched_launch.run(sched_launch.parse_args([
+    records = sched_launch.main([
         "--devices", "4", "--servers", "2", "--jobs", "3", "--steps", "2",
-        "--seed", str(seed)]))
+        "--seed", str(seed)])
     devices = jax.devices()
     for r in records:
         want = [devices[g].id for g in r["gpus"]]
@@ -426,18 +482,8 @@ def main(argv=None) -> int:
         print(f"chip_smoke: JAX found no TPU (backend "
               f"{jax.default_backend()!r}); refusing to run", file=sys.stderr)
         return 2
-    if args.chips == 4:
-        # The cross-chip programs compile afresh: an executable for a
-        # subset of the chips, read back from a persistent cache written
-        # on another host of the same topology, halted the core ("Invalid
-        # logical z: enhanced-barrier"); compiled anew, it ran.
-        from jax.experimental.compilation_cache import compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        cache = "off"
-    else:
-        from repro.launch.mesh import use_compile_cache
-        cache = use_compile_cache()
+    from repro.launch.mesh import use_compile_cache
+    cache = use_compile_cache(args.chips)
     devices = jax.devices()
     if len(devices) < args.chips:
         print(f"chip_smoke: --chips {args.chips} but {len(devices)} "

@@ -333,17 +333,16 @@ def resolve_placement(params: dict, n_jobs: int | None = None) -> str:
 def resolve_columnar_backend(params: dict) -> str:
     """The request's ``columnar_backend`` param, resolved (default "auto").
 
-    "auto" resolves from the platform: the compiled Pallas "kernel"
-    programs of :mod:`repro.kernels.placement` on TPU, the fused XLA
-    "jit" programs elsewhere (on CPU short batches still take NumPy,
-    below ``DISPATCH_MIN_ROWS``); "jit"/"kernel"/"numpy" force a backend.
-    All backends make the same decisions (pinned by
+    "auto" is the fused XLA "jit" programs of
+    :mod:`repro.kernels.placement` on every platform (on CPU short
+    batches still take NumPy, below ``DISPATCH_MIN_ROWS``): on a TPU v5e
+    the Pallas "kernel" programs were no faster.  "jit"/"kernel"/"numpy"
+    force a backend.  All backends make the same decisions (pinned by
     ``tests/test_columnar_equivalence.py``).
     """
     backend = params.get("columnar_backend", "auto")
     if backend == "auto":
-        import jax
-        return "kernel" if jax.default_backend() == "tpu" else "jit"
+        return "jit"
     from repro.core.columnar import COLUMNAR_BACKENDS
     if backend not in COLUMNAR_BACKENDS:
         raise ValueError(

@@ -478,8 +478,8 @@ def sjf_bco_policy(request: ScheduleRequest) -> ScheduleResult:
         ``sweep="sequential"`` or ``warm_start=True`` fall back to the
         scalar walk.
       * ``columnar_backend`` -- where the columnar step's array math
-        runs: ``"auto"`` (default; the Pallas kernels on TPU, the fused
-        jit programs elsewhere), ``"jit"``, ``"kernel"`` (Pallas row
+        runs: ``"auto"`` (default; the fused jit programs on every
+        platform), ``"jit"``, ``"kernel"`` (Pallas row
         kernels, interpret mode on CPU) or ``"numpy"`` -- all with the
         same decisions (see
         :func:`~repro.core.api.resolve_columnar_backend`).

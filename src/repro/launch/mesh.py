@@ -42,13 +42,26 @@ def peak_rates(device_kind: str) -> dict[str, float]:
                        f"known: {sorted(PEAK_RATES)}") from None
 
 
-def use_compile_cache() -> str:
-    """Turn on JAX's persistent compilation cache and return its path.
+def use_compile_cache(n_devices: int = 1) -> str:
+    """Set up JAX's persistent compilation cache for a process whose
+    programs run on ``n_devices`` devices; returns its path, or "off".
 
-    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
-    nothing else is set; otherwise the cache lives at
+    A process on more than one device caches nothing.  On a TPU v5e 2x2
+    host, a two-chip program read back from a persistent cache written
+    on another host of the same topology halted a core ("Invalid logical
+    z: enhanced-barrier"); the same program compiled anew ran.  That was
+    seen once and not reproduced, so the cache stays off for every
+    multi-device process until the cause is known.
+
+    Otherwise, where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and nothing else is set; else the cache lives at
     :data:`COMPILE_CACHE_DIR`, a fixed path (the path is part of the cache
     key, so it never comes from a temp name, a pid or the time)."""
+    if n_devices > 1:
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        return "off"
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -129,14 +129,14 @@ def run(args: argparse.Namespace) -> list[dict]:
     return records
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> list[dict]:
     args = parse_args(argv)
     if args.virtual_cpu:
         from repro.launch.mesh import request_cpu_devices
         request_cpu_devices(args.devices)
     from repro.launch.mesh import use_compile_cache
-    use_compile_cache()
-    run(args)
+    use_compile_cache(args.devices)
+    return run(args)
 
 
 if __name__ == "__main__":

@@ -132,8 +132,8 @@ def main(argv=None) -> None:
     if args.virtual_cpu:
         from repro.launch.mesh import request_cpu_devices
         request_cpu_devices(max(args.devices, 1))
-    from repro.launch.mesh import use_compile_cache
-    use_compile_cache()
+    from repro.launch.mesh import take_devices, use_compile_cache
+    use_compile_cache(len(take_devices(args.devices)))
     run(args)
 
 
