@@ -53,6 +53,7 @@ from typing import Callable, Protocol, runtime_checkable
 
 import numpy as np
 
+from repro import spans
 from repro.core import contention
 from repro.core.cluster import Cluster
 from repro.core.contention import (evaluate_many, predict_exec_time,
@@ -948,6 +949,11 @@ def probe_thetas(left: float, right: float, levels: int,
     return nodes
 
 
+#: Bisection work: ``rounds`` counts :func:`bisect_theta`'s calls into the
+#: policy (one ``attempt_many`` or ``attempt`` each).
+SEARCH_COUNTS = {"rounds": 0}
+
+
 def bisect_theta(attempt: Callable[..., "ScheduleResult | None"],
                  horizon: int, policy: str,
                  warm_start: bool = False,
@@ -992,6 +998,7 @@ def bisect_theta(attempt: Callable[..., "ScheduleResult | None"],
     left, right = 1.0, float(horizon)
     speculative = attempt_many is not None and not warm_start and levels > 1
     results: dict[float, ScheduleResult | None] = {}
+    rounds = 0
     while left <= right:
         theta = 0.5 * (left + right)
         if speculative:
@@ -1013,7 +1020,10 @@ def bisect_theta(attempt: Callable[..., "ScheduleResult | None"],
                     else floor
                 todo = [th for th in probe_thetas(left, right, levels, cut)
                         if th not in results]
-                results.update(attempt_many(todo))
+                with spans.span(spans.SEARCH_ROUND, round=rounds):
+                    results.update(attempt_many(todo))
+                rounds += 1
+                SEARCH_COUNTS["rounds"] += 1
             while left <= right:
                 theta = 0.5 * (left + right)
                 if theta not in results:
@@ -1027,7 +1037,10 @@ def bisect_theta(attempt: Callable[..., "ScheduleResult | None"],
                 else:
                     left = theta + 1.0
             continue
-        cand = attempt(theta, prev) if warm_start else attempt(theta)
+        with spans.span(spans.SEARCH_ROUND, round=rounds):
+            cand = attempt(theta, prev) if warm_start else attempt(theta)
+        rounds += 1
+        SEARCH_COUNTS["rounds"] += 1
         if cand is not None:
             prev = cand
             if best is None or cand.est_makespan <= best.est_makespan:
@@ -1104,6 +1117,6 @@ __all__ = [
     "PlacementState", "Picker", "Chooser", "SharedState",
     "ColumnarPlacement", "PLACEMENTS", "resolve_placement",
     "try_place", "try_place_group", "finalize", "bisect_theta",
-    "probe_thetas", "schedule_arrivals",
+    "SEARCH_COUNTS", "probe_thetas", "schedule_arrivals",
     "pick_best_finish", "nominal_rho", "rho_hat",
 ]

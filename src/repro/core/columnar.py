@@ -56,6 +56,7 @@ import bisect as _bisect
 
 import numpy as np
 
+from repro import spans
 from repro.core import contention
 from repro.core.cluster import Cluster
 from repro.core.contention import (_job_terms, evaluate_stack,
@@ -447,6 +448,10 @@ class ColumnarPlacement:
         where the scalar walk's decisions diverge; committed branches are
         re-merged onto deduplicated child rows.
         """
+        with spans.span(spans.COLUMNAR_PLACE, jid=job.jid):
+            self._place(job, rho_nom, pickers, picker_of)
+
+    def _place(self, job: Job, rho_nom: float, pickers, picker_of) -> None:
         if pickers is not self._checked_pickers:
             for picker in pickers:
                 if not getattr(picker, "theta_pool", False) \
@@ -631,7 +636,8 @@ class ColumnarPlacement:
                     w.scored[key] = None      # claimed; filled by _score
                     need.append((w, key, g))
             if need:
-                self._score(job, need)
+                with spans.span(spans.COLUMNAR_SCORE):
+                    self._score(job, need)
             # Eq. (16) re-check: each run splits into a committing upper
             # theta range and a retrying lower one.  All runs place the
             # same G-gang, so the refined-rho bounds come from one batched
@@ -674,7 +680,8 @@ class ColumnarPlacement:
                 break
         for w in work:                        # escalation ladder exhausted
             dead.append(w.branches)
-        self._apply(job, commits, dead)
+        with spans.span(spans.COLUMNAR_APPLY):
+            self._apply(job, commits, dead)
 
     def _apply(self, job: Job, commits: list[tuple],
                dead: list[np.ndarray]) -> None:

@@ -64,6 +64,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import spans
+
 __all__ = ["pick_orders", "score_probes", "compile_counts",
            "DISPATCH_COUNTS", "min_dispatch_rows"]
 
@@ -398,6 +400,7 @@ def _score_host(cluster, job, Y, p):
 # --------------------------------------------------------------------------
 
 
+@spans.spanned(spans.PICK_ORDERS)
 def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
                 th_hi: np.ndarray, rho_u: np.ndarray, pid: np.ndarray,
                 job, *, use_kernel: bool = False,
@@ -418,12 +421,9 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
     ``_fa_ffp_many`` / ``_lbsgf_many`` term for term.  On CPU, batches
     under :data:`DISPATCH_MIN_ROWS` skip the device round-trip.
     """
-    nw, N = U_stack.shape
+    nw = U_stack.shape[0]
     G = job.num_gpus
     consts = _cluster_consts(cluster)
-    gpu_server = consts["np_gpu_server"]
-    caps = consts["np_caps"]
-    S = caps.shape[0]
     V = U_stack + rho_u[:, None]
     feas = V <= th_lo[:, None] + 1e-9                  # Eq. (16) pool
     if use_kernel or nw >= min_dispatch_rows():
@@ -433,18 +433,20 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
         def col(a, dtype=f32):
             return _pad_rows(np.asarray(a).astype(dtype)[:, None], R)
 
-        with jax.enable_x64(False):
+        operands = (
+            _pad_rows(U_stack.astype(f32), R), col(th_lo + 1e-9),
+            col(th_hi + 1e-9), col(rho_u), col(np.full(nw, G), np.int32),
+            _pad_rows(np.asarray(pid, dtype=np.int32), R),
+            # cum < lambda*G over integer capacities == cum < ceil(.)
+            np.float32(math.ceil(job.lam * G)),
+            consts["member"], consts["caps"])
+        with spans.span(spans.LAUNCH, rows=nw), jax.enable_x64(False):
             outs = _pool_stats_jit(
-                _pad_rows(U_stack.astype(f32), R), col(th_lo + 1e-9),
-                col(th_hi + 1e-9), col(rho_u), col(np.full(nw, G), np.int32),
-                _pad_rows(np.asarray(pid, dtype=np.int32), R),
-                # cum < lambda*G over integer capacities == cum < ceil(.)
-                np.float32(math.ceil(job.lam * G)),
-                consts["member"], consts["caps"],
-                load_rel=consts["load_rel"], use_kernel=use_kernel,
-                interpret=_interpret(interpret))
-        c_lo, c_hi, key, best_srv, has_fit, unsure = (
-            np.asarray(o)[:nw] for o in outs)
+                *operands, load_rel=consts["load_rel"],
+                use_kernel=use_kernel, interpret=_interpret(interpret))
+        with spans.span(spans.FETCH):
+            c_lo, c_hi, key, best_srv, has_fit, unsure = (
+                np.asarray(o)[:nw] for o in outs)
         c_lo, c_hi = c_lo.astype(np.int64), c_hi.astype(np.int64)
         key, has_fit = key.astype(np.float64), has_fit.astype(bool)
         best_srv = best_srv.astype(np.int64)
@@ -453,14 +455,29 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
         DISPATCH_COUNTS["rows"] += nw
         if redo.size:
             DISPATCH_COUNTS["rechecked"] += redo.size
-            (c_lo[redo], c_hi[redo], key[redo], best_srv[redo],
-             has_fit[redo]) = _pool_stats_host(
-                cluster, U_stack[redo], V[redo], feas[redo], th_hi[redo], G)
+            with spans.span(spans.RECHECK):
+                (c_lo[redo], c_hi[redo], key[redo], best_srv[redo],
+                 has_fit[redo]) = _pool_stats_host(
+                    cluster, U_stack[redo], V[redo], feas[redo], th_hi[redo],
+                    G)
     else:
         DISPATCH_COUNTS["host"] += 1
         c_lo, c_hi, key, best_srv, has_fit = _pool_stats_host(
             cluster, U_stack, V, feas, th_hi, G)
-    U = U_stack
+    with spans.span(spans.RANK):
+        order, ok = _rank(U_stack, feas, pid, c_lo, key, best_srv, has_fit,
+                          consts, job)
+    return V, c_lo, c_hi, order, ok
+
+
+def _rank(U, feas, pid, c_lo, key, best_srv, has_fit, consts, job):
+    """:func:`pick_orders`' stable GPU orderings and pool-large-enough
+    flags, from the program's reductions."""
+    nw, N = U.shape
+    G = job.num_gpus
+    gpu_server = consts["np_gpu_server"]
+    caps = consts["np_caps"]
+    S = caps.shape[0]
     order = np.empty((nw, N), dtype=np.int64)
     ok = np.empty(nw, dtype=bool)
     fa = np.flatnonzero(pid == 0)
@@ -496,9 +513,10 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
         flat = np.lexsort((k_U.ravel(), k_rank.ravel(),
                            np.repeat(np.arange(nl), N)))
         order[lb] = flat.reshape(nl, N) - r_off
-    return V, c_lo, c_hi, order, ok
+    return order, ok
 
 
+@spans.spanned(spans.SCORE_PROBES)
 def score_probes(cluster, job, Y: np.ndarray, p: np.ndarray, *,
                  use_kernel: bool = False, interpret: bool | None = None
                  ) -> np.ndarray:
@@ -534,22 +552,26 @@ def score_probes(cluster, job, Y: np.ndarray, p: np.ndarray, *,
     scal[0, :5] = (2.0 * share, share, share / cluster.gpu_speed, compute,
                    float(job.iters))
     consts = _cluster_consts(cluster)
-    with jax.enable_x64(False):
+    operands = (
+        _pad_rows(Y.astype(np.int32), R),
+        _pad_rows(f.astype(f32)[:, None], R, 1.0),
+        _pad_rows(gamma.astype(f32)[:, None], R), scal,
+        consts["speed_floor"], consts["uplink_shared"],
+        consts["uplink_isolated"])
+    with spans.span(spans.LAUNCH, rows=C), jax.enable_x64(False):
         rho, unsure = _score_probes_jit(
-            _pad_rows(Y.astype(np.int32), R),
-            _pad_rows(f.astype(f32)[:, None], R, 1.0),
-            _pad_rows(gamma.astype(f32)[:, None], R), scal,
-            consts["speed_floor"], consts["uplink_shared"],
-            consts["uplink_isolated"], hetero=cluster.is_heterogeneous,
+            *operands, hetero=cluster.is_heterogeneous,
             b_inter=float(cluster.b_inter), b_intra=float(cluster.b_intra),
             use_kernel=use_kernel, interpret=_interpret(interpret))
-    rho = np.asarray(rho)[:C].astype(np.float64)
-    redo = np.flatnonzero(np.asarray(unsure)[:C])
+    with spans.span(spans.FETCH):
+        rho = np.asarray(rho)[:C].astype(np.float64)
+        redo = np.flatnonzero(np.asarray(unsure)[:C])
     DISPATCH_COUNTS["device"] += 1
     DISPATCH_COUNTS["rows"] += C
     if redo.size:
         DISPATCH_COUNTS["rechecked"] += redo.size
-        rho[redo] = _score_host(cluster, job, Y[redo], p[redo])
+        with spans.span(spans.RECHECK):
+            rho[redo] = _score_host(cluster, job, Y[redo], p[redo])
     return rho
 
 
