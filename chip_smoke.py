@@ -148,16 +148,14 @@ def check_lowering(cluster, n_jobs: int) -> None:
     N, S = cluster.num_gpus, cluster.num_servers
     f32, i32 = jnp.float32, jnp.int32
     sds = jax.ShapeDtypeStruct
-    col = sds((8, 1), f32)
     with jax.enable_x64(False):
         pool = kp._pool_stats_jit.lower(
-            sds((8, N), f32), col, col, col, sds((8, 1), i32),
-            sds((8,), i32), sds((), f32), sds((N, S), f32), sds((1, S), f32),
+            sds((8, N + 6), f32), sds((N, S), f32), sds((1, S), f32),
             load_rel=1e-4, use_kernel=True, interpret=False).as_text()
         score = kp._score_probes_jit.lower(
-            sds((8, S), i32), col, col, sds((1, 8), f32), sds((1, S), f32),
-            sds((1, S), f32), sds((1, S), f32), hetero=True, b_inter=1.0,
-            b_intra=10.0, use_kernel=True, interpret=False).as_text()
+            sds((8, S + 7), f32), sds((1, S), f32), sds((1, S), f32),
+            sds((1, S), f32), hetero=True, b_inter=1.0, b_intra=10.0,
+            use_kernel=True, interpret=False).as_text()
         counts_txt = kt._stack_counts_jit.lower(
             sds((64, n_jobs, S), i32), sds((1, n_jobs, 1), i32),
             interpret=False).as_text()

@@ -24,6 +24,14 @@ reductions in VMEM; interpret mode on CPU, Mosaic on TPU).  Rows are
 padded to power-of-two buckets, so the programs retrace only per (bucket,
 cluster), never per job.
 
+A call crosses the host-device boundary twice: one float32 operand
+buffer goes in (:func:`_operand_buffer`: the ``[rows, width]`` block, then
+one column per per-row or per-call value), and one int32 result buffer
+comes out (:func:`_result_buffer`: integer columns, float32 blocks as
+their bits).  The per-cluster constants stay on the device.  A call's
+device work is a few microseconds, far less than one crossing costs, so
+the number of crossings, not their bytes, sets a call's time.
+
 **Precision contract: the same decisions as the float64 host oracle.**
 The programs compute in int32/float32 (Mosaic has no 64-bit types, and
 XLA's emulated f64 on TPU is not IEEE binary64).  Integer reductions --
@@ -87,8 +95,10 @@ SCORE_REL = 2.0 ** -17
 _PHI_MAX = 2.0 ** 23            # beyond this floor(1/tau) is not f32-exact
 
 #: Calls that ran the device program / took the host NumPy path below the
-#: CPU gate, rows dispatched, and rows re-checked in float64 on the host.
-DISPATCH_COUNTS = {"device": 0, "host": 0, "rows": 0, "rechecked": 0}
+#: CPU gate, rows dispatched, rows re-checked in float64 on the host, and
+#: arrays that crossed the host-device boundary (either way).
+DISPATCH_COUNTS = {"device": 0, "host": 0, "rows": 0, "rechecked": 0,
+                   "transfers": 0}
 
 
 def min_dispatch_rows() -> int:
@@ -106,12 +116,54 @@ def _bucket(n: int) -> int:
     return max(ROW_BLOCK, 1 << (max(1, n) - 1).bit_length())
 
 
-def _pad_rows(a: np.ndarray, R: int, fill=0) -> np.ndarray:
-    """``a`` with its leading axis padded to ``R`` rows of ``fill``."""
-    if a.shape[0] == R:
-        return a
-    pad = np.full((R - a.shape[0],) + a.shape[1:], fill, dtype=a.dtype)
-    return np.concatenate([a, pad])
+def _operand_buffer(R: int, block: np.ndarray, *cols) -> np.ndarray:
+    """A device call's one float32 operand buffer ``[R, W + len(cols)]``.
+
+    ``block`` ``[n, W]`` fills the first ``W`` columns, each of ``cols``
+    (a length-``n`` array or a scalar) one column after it, and rows
+    ``n..R`` are zero.  Every value is rounded to float32 once, as
+    ``astype`` rounds it; the integers carried (GPU and occupancy
+    counts, picker ids, ``ceil(lambda*G)``, iterations < 2^24) are
+    exact."""
+    n, W = block.shape
+    buf = np.empty((R, W + len(cols)), dtype=np.float32)
+    buf[:n, :W] = block
+    for j, c in enumerate(cols, W):
+        buf[:n, j] = c
+    buf[n:] = 0.0
+    return buf
+
+
+def _result_buffer(*blocks):
+    """A device call's one int32 result buffer: the ``[R, k]`` blocks side
+    by side, integer and boolean blocks as int32 and float32 blocks as
+    their bits (``bitcast_convert_type``), so every value comes back
+    exactly."""
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(b, jnp.int32)
+         if b.dtype == jnp.float32 else _i32(b) for b in blocks], axis=1)
+
+
+def _round_trip(program, ops: np.ndarray, consts: tuple, rows: int,
+                **static) -> np.ndarray:
+    """One device call: ``program`` on the operand buffer ``ops`` and the
+    device-resident ``consts``; returns the first ``rows`` rows of its
+    result buffer on the host.
+
+    The launch span holds the jit call (the operands' host-to-device
+    copies and the enqueue), the fetch span the ``np.asarray`` (the wait
+    for the program and the device-to-host copy).  ``DISPATCH_COUNTS
+    ["transfers"]`` counts the host arrays sent and the buffer fetched."""
+    with spans.span(spans.LAUNCH, rows=rows), jax.enable_x64(False):
+        out = program(ops, *consts, **static)
+    DISPATCH_COUNTS["transfers"] += sum(
+        isinstance(a, np.ndarray) for a in (ops, *consts))
+    with spans.span(spans.FETCH):
+        out = np.asarray(out)[:rows]
+    DISPATCH_COUNTS["transfers"] += 1
+    DISPATCH_COUNTS["device"] += 1
+    DISPATCH_COUNTS["rows"] += rows
+    return out
 
 
 @functools.lru_cache(maxsize=64)
@@ -195,9 +247,10 @@ def _score_row_math(Y, f, gamma, scal, speed_floor, uplink_sh, uplink_iso,
                     *, hetero, b_inter, b_intra):
     """Eq. (6)-(8) tau -> rho-hat slots for a ``[R, S]`` candidate block.
 
+    ``Y`` holds the occupancy counts (only ``Y > 0`` is read),
     ``f``/``gamma`` are ``[R, 1]`` host-computed contention terms, ``scal``
-    the ``[1, 8]`` job row (two_share, share, reduce_const, compute, iters)
-    and the device terms ``[1, S]``.  Returns int32 ``[R, 1]`` columns
+    the ``[R, 5]`` job columns (two_share, share, reduce_const, compute,
+    iters) and the device terms ``[1, S]``.  Returns int32 ``[R, 1]`` columns
     ``(rho, unsure)``: rho = ceil(iters / max(1, floor(1/tau))) exactly
     whenever ``unsure`` is 0."""
     two_share, share = scal[:, 0:1], scal[:, 1:2]
@@ -282,16 +335,23 @@ _PARALLEL = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 @functools.partial(jax.jit, static_argnames=("load_rel", "use_kernel",
                                              "interpret"))
-def _pool_stats_jit(U, t_lo, t_hi, rho_u, G, pid, need, member, caps, *,
-                    load_rel, use_kernel, interpret):
+def _pool_stats_jit(ops, member, caps, *, load_rel, use_kernel, interpret):
     """One program: pools, thresholds, per-server reductions, screens.
 
-    Returns ``(c_lo, c_hi, key, best_srv, has_fit, unsure)``: ``key`` is
-    the LBSGF ``load/cap`` ranking key, and ``unsure`` flags rows whose
+    ``ops`` is the ``[B, N + 6]`` operand buffer: the busy-time rows
+    ``U``, then the columns ``t_lo``, ``t_hi`` (thresholds with the
+    +1e-9), ``rho_u``, ``G``, ``pid`` and ``need`` (``ceil(lambda*G)``).
+    Returns the int32 ``[B, S + 5]`` result buffer: ``c_lo``, ``c_hi``,
+    ``best_srv``, ``has_fit``, ``unsure``, then the bits of the float32
+    LBSGF ``load/cap`` ranking keys.  ``unsure`` flags rows whose
     decisions the float64 host must recompute (pool thresholds; the
     FA-FFP tie-break for picker 0, the LBSGF ranking for picker 1)."""
-    B, N = U.shape
-    S = member.shape[1]
+    B = ops.shape[0]
+    N, S = member.shape
+    U = ops[:, :N]
+    t_lo, t_hi, rho_u, G, pid, need = (ops[:, j:j + 1]
+                                       for j in range(N, N + 6))
+    G = _i32(G)
     if use_kernel:
         col = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         outs = pl.pallas_call(
@@ -326,18 +386,23 @@ def _pool_stats_jit(U, t_lo, t_hi, rho_u, G, pid, need, member, caps, *,
     close = close & (sid[:, None] != sid[None, :])[None]
     lb_u = jnp.any(close & (in_prefix[:, :, None] | in_prefix[:, None, :]),
                    axis=(1, 2))
-    unsure = (pool_u[:, 0] > 0) | jnp.where(pid == 0, fa_u[:, 0] > 0, lb_u)
-    return c_lo[:, 0], c_hi[:, 0], key, best[:, 0], fit[:, 0], unsure
+    unsure = (pool_u > 0) | jnp.where(pid == 0, fa_u > 0, lb_u[:, None])
+    return _result_buffer(c_lo, c_hi, best, fit, unsure, key)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "hetero", "b_inter", "b_intra", "use_kernel", "interpret"))
-def _score_probes_jit(Y, f, gamma, scal, speed_floor, uplink_sh,
-                      uplink_iso, *, hetero, b_inter, b_intra, use_kernel,
-                      interpret):
+def _score_probes_jit(ops, speed_floor, uplink_sh, uplink_iso, *, hetero,
+                      b_inter, b_intra, use_kernel, interpret):
     """One program: Eq. (6)-(8) rho-hat slots + screen for a candidate
-    batch.  ``f``/``gamma`` come from the host (see :func:`score_probes`)."""
-    B, S = Y.shape
+    batch.  ``ops`` is the ``[B, S + 7]`` operand buffer: the occupancy
+    rows ``Y``, then the columns ``f``, ``gamma`` (host-computed, see
+    :func:`score_probes`) and the job's five ``scal`` terms.  Returns the
+    int32 ``[B, 2]`` result buffer ``(rho, unsure)``."""
+    B = ops.shape[0]
+    S = speed_floor.shape[1]
+    Y, f, gamma, scal = (ops[:, :S], ops[:, S:S + 1], ops[:, S + 1:S + 2],
+                         ops[:, S + 2:])
     kw = dict(hetero=hetero, b_inter=b_inter, b_intra=b_intra)
     if not use_kernel:
         rho, unsure = _score_row_math(Y, f, gamma, scal, speed_floor,
@@ -348,14 +413,14 @@ def _score_probes_jit(Y, f, gamma, scal, speed_floor, uplink_sh,
             functools.partial(_score_kernel, **kw),
             grid=(B // ROW_BLOCK,),
             in_specs=[_row_spec(S), _row_spec(1), _row_spec(1),
-                      _whole_spec((1, 8)), _whole_spec((1, S)),
+                      _row_spec(5), _whole_spec((1, S)),
                       _whole_spec((1, S)), _whole_spec((1, S))],
             out_specs=[_row_spec(1), _row_spec(1)],
             out_shape=[col, col],
             compiler_params=_PARALLEL,
             interpret=interpret,
         )(Y, f, gamma, scal, speed_floor, uplink_sh, uplink_iso)
-    return rho[:, 0], unsure[:, 0]
+    return _result_buffer(rho, unsure)
 
 
 # --------------------------------------------------------------------------
@@ -427,32 +492,18 @@ def pick_orders(cluster, U_stack: np.ndarray, th_lo: np.ndarray,
     V = U_stack + rho_u[:, None]
     feas = V <= th_lo[:, None] + 1e-9                  # Eq. (16) pool
     if use_kernel or nw >= min_dispatch_rows():
-        R = _bucket(nw)
-        f32 = np.float32
-
-        def col(a, dtype=f32):
-            return _pad_rows(np.asarray(a).astype(dtype)[:, None], R)
-
-        operands = (
-            _pad_rows(U_stack.astype(f32), R), col(th_lo + 1e-9),
-            col(th_hi + 1e-9), col(rho_u), col(np.full(nw, G), np.int32),
-            _pad_rows(np.asarray(pid, dtype=np.int32), R),
+        ops = _operand_buffer(
+            _bucket(nw), U_stack, th_lo + 1e-9, th_hi + 1e-9, rho_u, G, pid,
             # cum < lambda*G over integer capacities == cum < ceil(.)
-            np.float32(math.ceil(job.lam * G)),
-            consts["member"], consts["caps"])
-        with spans.span(spans.LAUNCH, rows=nw), jax.enable_x64(False):
-            outs = _pool_stats_jit(
-                *operands, load_rel=consts["load_rel"],
-                use_kernel=use_kernel, interpret=_interpret(interpret))
-        with spans.span(spans.FETCH):
-            c_lo, c_hi, key, best_srv, has_fit, unsure = (
-                np.asarray(o)[:nw] for o in outs)
-        c_lo, c_hi = c_lo.astype(np.int64), c_hi.astype(np.int64)
-        key, has_fit = key.astype(np.float64), has_fit.astype(bool)
-        best_srv = best_srv.astype(np.int64)
+            math.ceil(job.lam * G))
+        out = _round_trip(
+            _pool_stats_jit, ops, (consts["member"], consts["caps"]), nw,
+            load_rel=consts["load_rel"], use_kernel=use_kernel,
+            interpret=_interpret(interpret))
+        c_lo, c_hi, best_srv, has_fit, unsure = out[:, :5].T.astype(np.int64)
+        has_fit = has_fit.astype(bool)
+        key = out[:, 5:].view(np.float32).astype(np.float64)
         redo = np.flatnonzero(unsure)
-        DISPATCH_COUNTS["device"] += 1
-        DISPATCH_COUNTS["rows"] += nw
         if redo.size:
             DISPATCH_COUNTS["rechecked"] += redo.size
             with spans.span(spans.RECHECK):
@@ -539,8 +590,6 @@ def score_probes(cluster, job, Y: np.ndarray, p: np.ndarray, *,
     from repro.core.contention import degradation
     if not job.iters < 2 ** 24:
         raise ValueError(f"iters={job.iters} is not exact in float32")
-    R = _bucket(C)
-    f32 = np.float32
     # The contention terms that take integer p/n_srv in (k, f, gamma) are
     # evaluated on the host in float64 and rounded once.
     f = degradation(cluster.alpha, np.maximum(cluster.xi1 * p, 1.0))
@@ -548,26 +597,19 @@ def score_probes(cluster, job, Y: np.ndarray, p: np.ndarray, *,
     w = float(job.num_gpus)
     share = (job.grad_size / w) * (w - 1.0) if w > 1 else 0.0
     compute = job.dt_fwd * float(job.batch) + job.dt_bwd
-    scal = np.zeros((1, 8), dtype=f32)
-    scal[0, :5] = (2.0 * share, share, share / cluster.gpu_speed, compute,
-                   float(job.iters))
     consts = _cluster_consts(cluster)
-    operands = (
-        _pad_rows(Y.astype(np.int32), R),
-        _pad_rows(f.astype(f32)[:, None], R, 1.0),
-        _pad_rows(gamma.astype(f32)[:, None], R), scal,
-        consts["speed_floor"], consts["uplink_shared"],
-        consts["uplink_isolated"])
-    with spans.span(spans.LAUNCH, rows=C), jax.enable_x64(False):
-        rho, unsure = _score_probes_jit(
-            *operands, hetero=cluster.is_heterogeneous,
-            b_inter=float(cluster.b_inter), b_intra=float(cluster.b_intra),
-            use_kernel=use_kernel, interpret=_interpret(interpret))
-    with spans.span(spans.FETCH):
-        rho = np.asarray(rho)[:C].astype(np.float64)
-        redo = np.flatnonzero(np.asarray(unsure)[:C])
-    DISPATCH_COUNTS["device"] += 1
-    DISPATCH_COUNTS["rows"] += C
+    ops = _operand_buffer(_bucket(C), Y, f, gamma, 2.0 * share, share,
+                          share / cluster.gpu_speed, compute,
+                          float(job.iters))
+    out = _round_trip(
+        _score_probes_jit, ops, (consts["speed_floor"],
+                                 consts["uplink_shared"],
+                                 consts["uplink_isolated"]), C,
+        hetero=cluster.is_heterogeneous, b_inter=float(cluster.b_inter),
+        b_intra=float(cluster.b_intra), use_kernel=use_kernel,
+        interpret=_interpret(interpret))
+    rho = out[:, 0].astype(np.float64)
+    redo = np.flatnonzero(out[:, 1])
     if redo.size:
         DISPATCH_COUNTS["rechecked"] += redo.size
         with spans.span(spans.RECHECK):

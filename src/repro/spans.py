@@ -16,8 +16,8 @@ is the "caused by" link.  Metadata names the work: ``round`` on rounds,
     repro.search.round              one bisection round (core.api)
       repro.columnar.place          one job's step over every live branch
         repro.placement.pick_orders pool stats + pick rankings, operand prep
-          repro.placement.launch    jit call on NumPy operands
-          repro.placement.fetch     np.asarray of the program's outputs
+          repro.placement.launch    jit call on the one operand buffer
+          repro.placement.fetch     np.asarray of the one result buffer
           repro.placement.recheck   float64 recompute of screened rows
           repro.placement.rank      NumPy FA-FFP / LBSGF stable rankings
         repro.columnar.score        rho-hat probe scoring

@@ -66,11 +66,10 @@ def _compile(fn, args, one_chip, **static):
 def test_pool_stats(one_chip, width, use_kernel):
     from repro.kernels.placement import _pool_stats_jit
     N, S, _ = _shapes(width)
-    f32, i32 = jnp.float32, jnp.int32
-    col = ((ROWS, 1), f32)
+    f32 = jnp.float32
+    # The operand buffer: U, then t_lo, t_hi, rho_u, G, pid, need.
     text = _compile(_pool_stats_jit,
-                    [((ROWS, N), f32), col, col, col, ((ROWS, 1), i32),
-                     ((ROWS,), i32), ((), f32), ((N, S), f32), ((1, S), f32)],
+                    [((ROWS, N + 6), f32), ((N, S), f32), ((1, S), f32)],
                     one_chip, load_rel=4e-5, use_kernel=use_kernel,
                     interpret=False)
     assert ("tpu_custom_call" in text) == use_kernel
@@ -84,9 +83,9 @@ def test_score_probes(one_chip, width, hetero, use_kernel):
     _, S, _ = _shapes(width)
     f32 = jnp.float32
     row = ((1, S), f32)
+    # The operand buffer: Y, then f, gamma and the job's five terms.
     text = _compile(_score_probes_jit,
-                    [((ROWS, S), jnp.int32), ((ROWS, 1), f32),
-                     ((ROWS, 1), f32), ((1, 8), f32), row, row, row],
+                    [((ROWS, S + 7), f32), row, row, row],
                     one_chip, hetero=hetero, b_inter=1.0, b_intra=10.0,
                     use_kernel=use_kernel, interpret=False)
     assert ("tpu_custom_call" in text) == use_kernel
