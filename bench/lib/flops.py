@@ -2,7 +2,7 @@
 
 Only matrix products count (the usual MFU convention): the projections,
 the causal attention products, the SwiGLU feed-forward, the image
-projector and the tied output head over the positions the loss reads.
+projector and the output head over the positions the loss reads.
 The embedding gather costs no multiply.  Nothing is counted for
 recomputation (remat) or for logits the loss never reads, so the count
 is what the model requires, whatever the implementation does.

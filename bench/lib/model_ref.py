@@ -5,20 +5,24 @@ nothing of the program under test.
 Forward: image patches through a linear projector, prepended to the
 embedded text; L pre-norm blocks of RMSNorm, grouped-query causal
 attention with full rotary embeddings (half-split rotation), and a SwiGLU
-feed-forward; a final RMSNorm and the tied output head.  The loss is the
-mean next-token cross-entropy of the text tokens, the last image position
-predicting the first text token.  AdamW with decoupled weight decay,
+feed-forward; a final RMSNorm and the output head (the embedding's
+transpose where ``tie_embeddings``, else a matrix of its own).  The loss
+is the mean next-token cross-entropy of the text tokens, the last image
+position predicting the first text token.  AdamW with decoupled weight decay,
 global-norm clipping and a linear-warmup cosine schedule.
 
 Precision: float32 with ``Precision.HIGHEST`` products (on a TPU a float32
 product otherwise runs in bfloat16).  ``fp8=True`` is the control: every
 product's operands are rounded to float8 e4m3 with a per-tensor scale,
 the step below the configuration's bfloat16 compute.  The gradient is
-accumulated over blocks of ``block`` sequences so that the step fits on
-one chip beside its parameters and optimizer state.
+accumulated over blocks of ``block`` sequences, and AdamW's moments wait
+on the host while the blocks run, so that the step fits on one chip
+beside its parameters.
 """
 from __future__ import annotations
 
+import functools
+import json
 import math
 from functools import partial
 
@@ -40,8 +44,9 @@ def param_shapes(m: dict) -> dict:
     layer weights carry a leading [n_layers] axis."""
     d, L, V = m["d_model"], m["n_layers"], m["vocab"]
     qd, kd, ff = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"], m["d_ff"]
+    head = {} if m["tie_embeddings"] else {"unembed": (d, V)}
     return {
-        "embed": (V, d), "ln_f": (d,), "projector": (d, d),
+        "embed": (V, d), "ln_f": (d,), "projector": (d, d), **head,
         "layers": {
             "ln1": (L, d), "ln2": (L, d),
             "attn": {"wq": (L, d, qd), "wk": (L, d, kd), "wv": (L, d, kd),
@@ -69,7 +74,7 @@ def make_weights(m: dict, key) -> dict:
         k = jax.random.fold_in(key, i)
         if "ln" in path:
             out.append(jnp.ones(shape, jnp.float32))
-        elif "embed" in path:
+        elif path == "['embed']":
             out.append(0.02 * jax.random.normal(k, shape, jnp.float32))
         else:
             out.append(jax.random.normal(k, shape, jnp.float32)
@@ -148,7 +153,10 @@ def loss_sum(m: dict, fp8: bool, params, tokens, patches):
     body = jax.checkpoint(partial(_block, m, fp8))
     x, _ = jax.lax.scan(lambda c, p: (body(c, p), None), x, params["layers"])
     h = _rms(x, params["ln_f"], m["norm_eps"])[:, P - 1:-1]
-    logits = _mm("bsd,vd->bsv", h, params["embed"], fp8)
+    if m["tie_embeddings"]:
+        logits = _mm("bsd,vd->bsv", h, params["embed"], fp8)
+    else:
+        logits = _mm("bsd,dv->bsv", h, params["unembed"], fp8)
     gold = jnp.take_along_axis(logits, tokens[..., None], axis=-1)[..., 0]
     return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - gold)
 
@@ -213,6 +221,29 @@ def leaf_norms(tree):
 # --------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _programs(m_json: str, o_json: str, fp8: bool, device) -> dict:
+    """The reference's jitted programs for one configuration, precision
+    and device, built once a process: every later seed, control and fault
+    of the process reuses them."""
+    m, o = json.loads(m_json), json.loads(o_json)
+    one = jax.sharding.SingleDeviceSharding(device)
+    return {
+        "put": partial(jax.device_put, device=one),
+        "weights": jax.jit(partial(make_weights, m), out_shardings=one),
+        "grad_block": jax.jit(jax.value_and_grad(partial(loss_sum, m, fp8))),
+        "add": jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
+                       donate_argnums=0),
+        "scale": jax.jit(lambda g, n: jax.tree.map(lambda x: x / n, g),
+                         donate_argnums=0),
+        "update": jax.jit(partial(adamw_update, o),
+                          donate_argnums=(0, 1, 2, 3)),
+        "norms": jax.jit(leaf_norms),
+        "change": jax.jit(lambda a, b: leaf_norms(
+            jax.tree.map(jnp.subtract, a, b))),
+    }
+
+
 def reference_steps(m: dict, o: dict, seed: int, batches: list[dict],
                     block: int, fp8: bool = False, device=None) -> dict:
     """Three (or ``len(batches)``) reference steps from the seeded weights.
@@ -221,41 +252,40 @@ def reference_steps(m: dict, o: dict, seed: int, batches: list[dict],
     clipped gradient (``m_1 / (1 - b1)``), the per-leaf norms of the
     parameters' change after the last step, and the per-leaf norms of
     the first raw gradient (which leaves have a gradient at all)."""
-    one = jax.sharding.SingleDeviceSharding(device or jax.devices()[0])
-    put = partial(jax.device_put, device=one)
-    weights = jax.jit(partial(make_weights, m), out_shardings=one)
-    params = weights(seed_key(seed))
-    mom = jax.tree.map(jnp.zeros_like, params)
-    vel = jax.tree.map(jnp.zeros_like, params)
-    grad_block = jax.jit(jax.value_and_grad(partial(loss_sum, m, fp8)))
-    add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
-    scale = jax.jit(lambda g, n: jax.tree.map(lambda x: x / n, g),
-                    donate_argnums=0)
-    update = jax.jit(partial(adamw_update, o), donate_argnums=(0, 1, 2, 3))
-    norms = jax.jit(leaf_norms)
+    f = _programs(json.dumps(m, sort_keys=True), json.dumps(o, sort_keys=True),
+                  fp8, device or jax.devices()[0])
+    params = f["weights"](seed_key(seed))
+    moments = None          # AdamW's (m, v), on the host between updates
     losses = []
     out: dict = {}
     for t, batch in enumerate(batches, start=1):
         B = batch["tokens"].shape[0]
         total, grads = 0.0, None
         for lo in range(0, B, block):
-            toks = put(batch["tokens"][lo:lo + block])
-            pats = put(batch["patches"][lo:lo + block])
-            val, g = grad_block(params, toks, pats)
+            val, g = f["grad_block"](params,
+                                     f["put"](batch["tokens"][lo:lo + block]),
+                                     f["put"](batch["patches"][lo:lo + block]))
             total += float(val)
-            grads = g if grads is None else add(grads, g)
+            grads = g if grads is None else f["add"](grads, g)
+            del g
         n = B * batch["tokens"].shape[1]
-        grads = scale(grads, float(n))
+        grads = f["scale"](grads, float(n))
         losses.append(total / n)
         if t == 1:
-            out["grad_raw_norms"] = np.asarray(norms(grads))
-        params, mom, vel = update(grads, params, mom, vel, lr_at(o, t),
-                                  1 - o["b1"] ** t, 1 - o["b2"] ** t)
+            out["grad_raw_norms"] = np.asarray(f["norms"](grads))
+        if moments is None:
+            mom = jax.tree.map(jnp.zeros_like, params)
+            vel = jax.tree.map(jnp.zeros_like, params)
+        else:
+            mom, vel = f["put"](moments)
+        params, mom, vel = f["update"](grads, params, mom, vel, lr_at(o, t),
+                                       1 - o["b1"] ** t, 1 - o["b2"] ** t)
         if t == 1:
-            out["grad_norms"] = np.asarray(norms(mom)) / (1 - o["b1"])
-    p0 = weights(seed_key(seed))
-    out["change_norms"] = np.asarray(jax.jit(
-        lambda a, b: leaf_norms(jax.tree.map(jnp.subtract, a, b)))(params, p0))
+            out["grad_norms"] = np.asarray(f["norms"](mom)) / (1 - o["b1"])
+        moments = jax.device_get((mom, vel))
+        del mom, vel
+    out["change_norms"] = np.asarray(f["change"](
+        params, f["weights"](seed_key(seed))))
     out["losses"] = losses
     return out
 

@@ -20,24 +20,11 @@ TINY_CLUSTER = {"capacities": [8, 4, 8, 4, 16, 8], "b_intra": 300.0,
 TINY_MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
               "head_dim": 16, "d_ff": 128, "vocab": 512, "n_patches": 8,
               "rope_theta": 10000.0, "norm_eps": 1e-6,
-              "tie_embeddings": True, "param_dtype": "float32",
+              "tie_embeddings": False, "param_dtype": "float32",
               "compute_dtype": "float32", "remat": False}
-# The training metrics, as a training cell's entries in BENCHMARK.json
-# give them.
-TRAIN_METRICS = {
-    "end_to_end": [
-        {"name": "train_tokens_per_s", "unit": "tokens/s", "better": "higher",
-         "bound": 0.01, "source": "host_clock"}],
-    "per_layer": [
-        {"name": "input_ms_per_step", "unit": "ms", "better": "lower",
-         "source": "host_clock", "layer": "input pipeline",
-         "moves": "train_tokens_per_s"},
-        {"name": "train_mfu", "unit": "%", "better": "higher",
-         "source": "host_clock", "layer": "train step",
-         "moves": "train_tokens_per_s"},
-        {"name": "device_idle_pct.train", "unit": "%", "better": "lower",
-         "source": "device_trace", "layer": "device",
-         "moves": "train_tokens_per_s"}]}
+# The cell of a test tree that stands in for the real cells of each kind
+# (the kind a traffic file names).
+TINY_CELLS = {"replan": "tiny.replan", "train": "vlm.train"}
 
 
 def make_tree(root: Path, width: int = 1) -> Path:
@@ -47,6 +34,9 @@ def make_tree(root: Path, width: int = 1) -> Path:
                     ignore=shutil.ignore_patterns("__pycache__"))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     b = root / "bench"
+    kind = {w["name"]: json.loads((b / "traffic" / f"{w['traffic']}.json")
+                                  .read_text())["driver"]
+            for w in spec["workloads"]}
     sched = json.loads((b / "configs" / "philly-20srv.json").read_text())
     sched.update(name="tiny", cluster=TINY_CLUSTER, horizon_min=200)
     sched["jobs"]["mix"] = [[1, 8], [2, 4], [4, 4], [8, 2]]
@@ -75,14 +65,13 @@ def make_tree(root: Path, width: int = 1) -> Path:
          "chips": 1, "why": "test"},
         {"name": "vlm.train", "config": "vlm", "traffic": "tiny-train",
          "chips": width, "why": "test"}]
-    for kind, entries in TRAIN_METRICS.items():
-        names = {m["name"] for m in entries}
-        spec[kind] = [m for m in spec[kind] if m["name"] not in names] + \
-            [dict(m, workloads=[]) for m in entries]
+    # Every metric as BENCHMARK.json gives it, reported in the test cell
+    # of the kind of each real cell that lists it.
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = ["tiny.replan"] if "replan" in m["name"] or \
-                m.get("moves") == "replan_s" else ["vlm.train"]
+            m["workloads"] = sorted({TINY_CELLS[kind[w]]
+                                     for w in m["workloads"]
+                                     if kind[w] in TINY_CELLS})
     path = root / "BENCHMARK.json"
     path.write_text(json.dumps(spec, indent=1))
     return path

@@ -27,7 +27,14 @@ def _run(tree):
                             require_tpu=False)
 
 
-def test_sound_run_is_correct(tree):
+@pytest.mark.parametrize("tie", [False, True], ids=["own_head", "tied"])
+def test_sound_run_is_correct(tree, tie):
+    """The program and the reference agree with the output head as the
+    configuration has it (its own matrix) and tied to the embedding."""
+    path = tree.parent / "bench" / "configs" / "vlm.json"
+    cfg = json.loads(path.read_text())
+    cfg["model"]["tie_embeddings"] = tie
+    path.write_text(json.dumps(cfg))
     out = _run(tree)
     assert out["correct"], out["checks"]
     assert out["attempted"] >= 1 and out["failed"] == 0
@@ -118,3 +125,22 @@ def test_worst_gap_by_leaf():
     assert i == 3 and gap == pytest.approx(0.5 / 1.5, rel=1e-5)
     gap, _ = worst_gap(got, ref, keep=np.array([True, False, True, True]))
     assert gap < 1e-5
+
+
+def test_calibrate_control_and_half_batch_read_above_the_limits(tree, monkeypatch):
+    """calibrate.py's readings: the program passes the limits, and the
+    float8 control and half the batch each fail one."""
+    from bench import calibrate
+    spec = json.loads(tree.read_text())
+    cell = next(w for w in spec["workloads"] if w["name"] == "vlm.train")
+    run = harness.Run(spec, cell, 9, 0.0, tree.parent / "bench")
+    run.devices = jax.devices()
+    lines = []
+    monkeypatch.setattr(calibrate, "emit", lambda kind, seed, **x:
+                        lines.append(dict(x, kind=kind)))
+    calibrate.train(run, [9, 10], [9])
+    assert [x["kind"] for x in lines] == ["program", "control", "half_batch",
+                                         "program"]
+    for x in lines:
+        above = [k for k in run.limits if x[k] > run.limits[k]]
+        assert bool(above) is (x["kind"] != "program"), x
