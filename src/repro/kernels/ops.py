@@ -6,12 +6,25 @@ with natural shapes.  On CPU the kernels execute in interpret mode; on TPU
 they compile to Mosaic."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import flash_attention as _jfa
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import swiglu as _sg
+
+
+# (query block, key block) caps of the training kernel's forward, dk/dv
+# and dq kernels: the fastest pairs of a sweep over {256, 512, 1024}^2 on
+# a TPU v5e at 2 x 4,096 positions, 14 heads of 64
+# (benchmarks/flash_block_sweep.py; PERF.md).  ``causal_blocks`` cuts
+# each cap to a block that divides the sequence.
+FWD_BLOCKS = (1024, 1024)
+DKV_BLOCKS = (1024, 1024)
+DQ_BLOCKS = (1024, 512)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
@@ -52,6 +65,51 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                               kv_len=Skv)
     if pad_q:
         out = out[:, :, :Sq]
+    return out.transpose(0, 2, 1, 3)
+
+
+def _divisor_block(seq: int, cap: int) -> int:
+    """``seq`` where it is no longer than ``cap``, else the power-of-two
+    ``cap`` halved until it divides ``seq`` (a multiple of 128)."""
+    b = min(cap, seq)
+    while seq % b:
+        b //= 2
+    return b
+
+
+def causal_blocks(seq: int, fwd: tuple = FWD_BLOCKS,
+                  dkv: tuple = DKV_BLOCKS,
+                  dq: tuple = DQ_BLOCKS) -> _jfa.BlockSizes:
+    """Block sizes of the training kernel at sequence length ``seq``: each
+    kernel's (query, key) caps, each cut to a block that divides ``seq``
+    (``_divisor_block``)."""
+    (fq, fk), (vq, vk), (qq, qk) = (
+        [_divisor_block(seq, b) for b in pair] for pair in (fwd, dkv, dq))
+    return _jfa.BlockSizes(
+        block_q=fq, block_k_major=fk, block_k=fk, block_b=1,
+        block_q_major_dkv=vq, block_k_major_dkv=vk, block_k_dkv=vk,
+        block_q_dkv=vq, block_k_major_dq=qk, block_k_dq=qk, block_q_dq=qq)
+
+
+def causal_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           blocks: _jfa.BlockSizes | None = None
+                           ) -> jax.Array:
+    """Causal attention on JAX's bundled TPU flash kernel, which has a
+    Pallas backward (dq and dk/dv kernels) and skips key blocks wholly
+    above the diagonal.  Model layout: q [B,S,H,hd], k/v [B,S,K,hd] (GQA:
+    the kv heads are repeated, so the repeat's VJP sums dk/dv over each
+    group); returns [B,S,H,hd].  Products take the inputs' dtype with f32
+    accumulation; the running max and denominator are f32.  ``S`` must be
+    a multiple of 128 and positions are ``0..S-1``."""
+    B, S, H, hd = q.shape
+    K = k.shape[2]
+    if H != K:
+        k = jnp.repeat(k, H // K, axis=2)
+        v = jnp.repeat(v, H // K, axis=2)
+    out = _jfa.flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=True, sm_scale=1.0 / math.sqrt(hd),
+        block_sizes=blocks or causal_blocks(S))
     return out.transpose(0, 2, 1, 3)
 
 

@@ -19,6 +19,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
 
 from repro.models.config import ModelConfig
 
@@ -324,6 +326,42 @@ def _sdpa_auto(q, k, v, q_pos, k_pos, *, causal, window, softcap,
         out.reshape(out.shape), BATCH_AXES, None, None)
 
 
+# Which path each attention call took, counted when the call is lowered
+# (``jax.jit(...).lower``), where the platform is known: "fused" is the
+# bundled Pallas kernel, "jnp" everything else.  Tests read it.
+ATTENTION_PATHS = {"fused": 0, "jnp": 0}
+
+_path_p = Primitive("attention_path")       # identity that counts a path
+
+
+def _count_path(x, *, path):
+    ATTENTION_PATHS[path] += 1
+    return x
+
+
+_path_p.def_impl(_count_path)
+_path_p.def_abstract_eval(lambda x, *, path: x)
+mlir.register_lowering(_path_p, lambda ctx, x, *, path: [
+    _count_path(x, path=path)])
+ad.primitive_jvps[_path_p] = lambda primals, tangents, *, path: (
+    _path_p.bind(*primals, path=path), tangents[0])
+batching.primitive_batchers[_path_p] = lambda args, dims, *, path: (
+    _path_p.bind(*args, path=path), dims[0])
+
+
+def _path(out: jax.Array, path: str) -> jax.Array:
+    return _path_p.bind(out, path=path)
+
+
+def _fused_eligible(cfg: ModelConfig, S: int, window, causal: bool) -> bool:
+    """The training path's causal self-attention goes to the fused kernel
+    where the chunked jnp path would engage (``S > q_chunk``, ``S`` a
+    multiple of 128), with no window, softcap or model-axis sharding."""
+    return (causal and isinstance(window, int) and window == 0
+            and not cfg.attn_softcap and bool(cfg.q_chunk)
+            and S > cfg.q_chunk and S % 128 == 0 and _model_axis_size() == 1)
+
+
 def attention(cfg: ModelConfig, p, x, q_pos, *, window=0, cache: KVCache | None = None,
               enc_out: jax.Array | None = None, rope: bool = True,
               causal: bool = True) -> tuple:
@@ -344,9 +382,10 @@ def attention(cfg: ModelConfig, p, x, q_pos, *, window=0, cache: KVCache | None 
 
     if enc_out is not None:
         k_pos = jnp.zeros((B, Skv), jnp.int32)                 # all valid
-        out = _sdpa_auto(q, k, v, q_pos, k_pos, causal=False, window=0,
-                         softcap=cfg.attn_softcap, compute_dtype=cd,
-                         q_chunk=cfg.q_chunk, n_heads=cfg.n_heads)
+        out = _path(_sdpa_auto(
+            q, k, v, q_pos, k_pos, causal=False, window=0,
+            softcap=cfg.attn_softcap, compute_dtype=cd, q_chunk=cfg.q_chunk,
+            n_heads=cfg.n_heads), "jnp")
         return out @ p["wo"].astype(cd), None
 
     if rope:
@@ -364,9 +403,25 @@ def attention(cfg: ModelConfig, p, x, q_pos, *, window=0, cache: KVCache | None 
                                        window=int(window),
                                        softcap=cfg.attn_softcap)
             return out.reshape(B, S, h * hd) @ p["wo"].astype(cd), None
-        out = _sdpa_auto(q, k, v, q_pos, q_pos, causal=causal, window=window,
-                         softcap=cfg.attn_softcap, compute_dtype=cd,
-                         q_chunk=cfg.q_chunk, n_heads=cfg.n_heads)
+
+        def jnp_path(q, k, v):
+            return _path(_sdpa_auto(
+                q, k, v, q_pos, q_pos, causal=causal, window=window,
+                softcap=cfg.attn_softcap, compute_dtype=cd,
+                q_chunk=cfg.q_chunk, n_heads=cfg.n_heads), "jnp")
+
+        if _fused_eligible(cfg, S, window, causal):
+            # Positions are 0..S-1 here, as every no-cache caller passes.
+            from repro.kernels import ops as kops
+
+            def fused_path(q, k, v):
+                return _path(kops.causal_flash_attention(q, k, v).reshape(
+                    B, S, h * hd), "fused")
+
+            out = jax.lax.platform_dependent(q, k, v, tpu=fused_path,
+                                             default=jnp_path)
+        else:
+            out = jnp_path(q, k, v)
         return out @ p["wo"].astype(cd), None
 
     # decode: write S new token(s) into slots q_pos % Smax, attend over cache
@@ -389,9 +444,9 @@ def attention(cfg: ModelConfig, p, x, q_pos, *, window=0, cache: KVCache | None 
         new_v = cache.v.at[bidx, slots].set(v.astype(cache.v.dtype))
         k_full, v_full = new_k.astype(cd), new_v.astype(cd)
         new_cache = KVCache(new_k, new_v, new_pos)
-    out = _sdpa(q, k_full, v_full, q_pos, new_pos,
-                causal=True, window=window, softcap=cfg.attn_softcap,
-                compute_dtype=cd)
+    out = _path(_sdpa(q, k_full, v_full, q_pos, new_pos,
+                      causal=True, window=window, softcap=cfg.attn_softcap,
+                      compute_dtype=cd), "jnp")
     return out @ p["wo"].astype(cd), new_cache
 
 

@@ -136,8 +136,10 @@ def run_stack(cfg: ModelConfig, stacked, x, q_pos, windows, *, kind: str,
     """Train/prefill pass over L scanned layers.  Returns (x, aux_sum)."""
 
     def body_fn(p, x, w):
-        y, _, _, aux = block_apply(cfg, p, x, q_pos, w, kind=kind,
-                                   enc_out=enc_out, causal=causal)
+        # With no window configured every layer's is 0: pass it as a
+        # Python 0, which the attention's dispatch can read at trace time.
+        y, _, _, aux = block_apply(cfg, p, x, q_pos, w if cfg.window else 0,
+                                   kind=kind, enc_out=enc_out, causal=causal)
         return y, aux
 
     body = _maybe_remat(body_fn, cfg)

@@ -194,3 +194,145 @@ class TestTauKernel:
         with pytest.raises(ValueError, match="tau backend"):
             with tau_backend("cuda"):
                 pass
+
+
+def _rel_err(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.abs(a - b).max() / np.abs(b).max())
+
+
+def _scaled_blocks(S):
+    """The dispatcher's block rule with its caps scaled from 4,096
+    positions down to ``S`` (to a power of two, 128 at least), so that a
+    short sequence still spans several blocks and masked ones are
+    skipped."""
+    def scaled(b):
+        return max(128, 1 << (b * S // 4096).bit_length() - 1)
+
+    return ops.causal_blocks(S, *(tuple(map(scaled, pair))
+                                  for pair in (ops.FWD_BLOCKS, ops.DKV_BLOCKS,
+                                               ops.DQ_BLOCKS)))
+
+
+class TestCausalFlashTraining:
+    """The training path's fused causal attention (JAX's bundled Pallas
+    kernel, in TPU interpret mode) against the model's own ``_sdpa``:
+    output and the gradients of q, k and v, at InternVL2's GQA 14/2 and
+    head_dim 64."""
+
+    @pytest.mark.parametrize("S,dtype,tol", [
+        (512, jnp.float32, 1e-5), (512, jnp.bfloat16, 2e-2),
+        (640, jnp.float32, 1e-5)])               # 640: blocks cut to 128
+    def test_matches_sdpa_with_grads(self, S, dtype, tol):
+        from jax.experimental.pallas import tpu as pltpu
+
+        from repro.models.layers import _sdpa
+        B, H, K, hd = 1, 14, 2, 64
+        ks = jax.random.split(jax.random.PRNGKey(6), 4)
+        q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
+        k = jax.random.normal(ks[1], (B, S, K, hd), dtype)
+        v = jax.random.normal(ks[2], (B, S, K, hd), dtype)
+        g = jax.random.normal(ks[3], (B, S, H * hd), dtype)
+        pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+        blocks = _scaled_blocks(S)
+
+        def fused(q, k, v):
+            return ops.causal_flash_attention(
+                q, k, v, blocks=blocks).reshape(B, S, H * hd)
+
+        def plain(q, k, v):
+            return _sdpa(q, k, v, pos, pos, causal=True, window=0,
+                         softcap=0.0, compute_dtype=dtype)
+
+        with pltpu.force_tpu_interpret_mode():
+            out, vjp = jax.vjp(jax.jit(fused), q, k, v)
+            grads = vjp(g)
+        exp, vjp_exp = jax.vjp(plain, q, k, v)
+        assert out.dtype == exp.dtype == dtype
+        assert _rel_err(out, exp) < tol
+        for got, want in zip(grads, vjp_exp(g)):
+            assert got.shape == want.shape
+            assert _rel_err(got, want) < tol
+
+    def test_block_rule(self):
+        """Blocks divide S: a cap that does not is halved (to 128); a cap
+        above S gives S."""
+        b = ops.causal_blocks(640, (512, 256), (256, 512), (1024, 512))
+        assert (b.block_q, b.block_k_major, b.block_q_major_dkv,
+                b.block_k_major_dkv, b.block_q_dq, b.block_k_major_dq) == \
+            (128, 128, 128, 128, 640, 128)
+        b = ops.causal_blocks(4096)
+        assert (b.block_q, b.block_k_major) == ops.FWD_BLOCKS
+        assert (b.block_q_major_dkv, b.block_k_major_dkv) == ops.DKV_BLOCKS
+        assert (b.block_q_dq, b.block_k_major_dq) == ops.DQ_BLOCKS
+        assert b.has_backward_blocks
+
+
+class TestAttentionDispatch:
+    """Which path ``layers.attention`` lowers to, read from
+    ``ATTENTION_PATHS``: the fused kernel only on a TPU target, for the
+    training path's causal self-attention at ``S > q_chunk``,
+    ``S % 128 == 0``, with no window, softcap, cache or encoder."""
+
+    @staticmethod
+    def _paths(fn, args, platform):
+        from repro.models import layers
+        layers.ATTENTION_PATHS.update(fused=0, jnp=0)
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=(platform,)).as_text()
+        assert ("tpu_custom_call" in text) == bool(
+            layers.ATTENTION_PATHS["fused"])
+        return dict(layers.ATTENTION_PATHS)
+
+    @staticmethod
+    def _case(arch, S, *extra, B=2, **kw):
+        """``attention`` of ``arch`` at ``B x S`` as a function of its
+        parameters, its input and ``extra`` traced keyword arguments."""
+        from repro.configs import get_config
+        from repro.models.layers import attention, init_attn
+        cfg = get_config(arch)
+        p = jax.eval_shape(lambda: init_attn(jax.random.PRNGKey(0), cfg,
+                                             jnp.float32))
+        x = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.bfloat16)
+        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        names = [n for n, _ in extra]
+
+        def fn(p, x, *vals):
+            return attention(cfg, p, x, pos, **kw,
+                             **dict(zip(names, vals)))[0]
+
+        return fn, (p, x, *(spec for _, spec in extra))
+
+    @pytest.mark.parametrize("platform,path", [("tpu", "fused"),
+                                               ("cpu", "jnp")])
+    def test_internvl2_cell_shapes(self, platform, path):
+        """The cell's shape (2 x 4,096, 14/2 heads of 64) differentiated
+        as the train step does: fused on a TPU target, jnp on the CPU."""
+        fn, args = self._case("internvl2-1b", 4096)
+        paths = self._paths(
+            jax.grad(lambda p, x: fn(p, x).astype(jnp.float32).sum(),
+                     argnums=(0, 1)), args, platform)
+        assert paths[path] > 0 and sum(paths.values()) == paths[path]
+
+    @pytest.mark.parametrize("case", ["short", "not_128", "softcap",
+                                      "cross", "decode", "traced_window"])
+    def test_other_calls_stay_on_jnp(self, case):
+        from repro.models.layers import init_kv_cache
+        bf = jnp.bfloat16
+        if case == "short":                      # S == q_chunk
+            fn, args = self._case("internvl2-1b", 512)
+        elif case == "not_128":
+            fn, args = self._case("internvl2-1b", 1000)
+        elif case == "softcap":
+            fn, args = self._case("gemma2-9b", 1024)
+        elif case == "cross":
+            enc = jax.ShapeDtypeStruct((2, 1024, 384), bf)
+            fn, args = self._case("whisper-tiny", 1024, ("enc_out", enc),
+                                  rope=False)
+        elif case == "decode":
+            cache = jax.eval_shape(lambda: init_kv_cache(2, 1024, 2, 64, bf))
+            fn, args = self._case("internvl2-1b", 1, ("cache", cache))
+        else:                                    # hymba's scanned windows
+            w = jax.ShapeDtypeStruct((), jnp.int32)
+            fn, args = self._case("hymba-1.5b", 1024, ("window", w))
+        assert self._paths(fn, args, "tpu") == {"fused": 0, "jnp": 1}

@@ -179,3 +179,44 @@ def test_rar_train_step_four_chips_full_width(topo):
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert held < 16e9, held
+
+
+def test_internvl2_train_step_fused_attention(topo):
+    """internvl2-1b's one-chip RAR step at the cell's 2 x 4,096 positions
+    (two layers): the causal attention is the fused Pallas kernel, forward
+    and backward, and no f32 score slab of a 512-query chunk over all
+    4,096 keys is left."""
+    import dataclasses
+    from functools import partial
+
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_config, input_specs
+    from repro.dist.steps import make_rar_train_step
+    from repro.models import build_model
+    from repro.models.config import InputShape
+    from repro.optim import adamw
+    from repro.optim.adamw import AdamWConfig
+
+    cfg = dataclasses.replace(get_config("internvl2-1b"), n_layers=2,
+                              n_patches=1792, tie_embeddings=False)
+    model = build_model(cfg, max_seq=4096)
+    ocfg = AdamWConfig()
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+
+    def on(tree, spec):
+        s = NamedSharding(mesh, spec)
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree)
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(partial(adamw.init, ocfg), params)
+    batch = input_specs(cfg, InputShape("cell", 4096, 2, "train"))
+    assert batch["tokens"].shape == (2, 4096 - 1792)
+    text = make_rar_train_step(model, ocfg, mesh).lower(
+        on(params, P()), on(opt, P()), on(batch, P("data"))
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"f32\[[0-9,]*512,4096\]", text)
